@@ -576,13 +576,21 @@ def shard_bench(report):
     its overhead over the plain dispatch is the substrate's fixed cost);
     ndev=8 needs 8 fake host devices, which XLA only grants BEFORE jax
     initializes, so it runs as a ``python -m benchmarks.kernel_bench
-    --shard-ndev 8`` subprocess whose rows are parsed and re-reported."""
+    --shard-ndev 8`` subprocess whose rows are parsed and re-reported.
+
+    On a TPU one process owns the chips, so a child could not reach them:
+    the multi-device rows run in-process over every visible chip instead."""
     import os
     import subprocess
     import sys
 
     for row in _shard_measurements(1):
         report(*row)
+    if jax.default_backend() == "tpu":
+        if jax.device_count() > 1:
+            for row in _shard_measurements(jax.device_count()):
+                report(*row)
+        return
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     # APPEND the device-count flag to any caller XLA_FLAGS: the ndev=8 rows
     # must run under the same compiler flags as the in-process ndev=1 rows
@@ -806,11 +814,23 @@ def shard2d_bench(report):
     (2,4) and (8,1) plus the 1e8-d end-to-end round. All shapes need 8
     fake host devices, which XLA only grants BEFORE jax initializes, so
     everything runs in one ``python -m benchmarks.kernel_bench --shard2d``
-    subprocess whose rows are parsed and re-reported."""
+    subprocess whose rows are parsed and re-reported.
+
+    On a TPU one process owns the chips, so the rows run in-process, and
+    only on a host with the 8 chips both mesh shapes need."""
     import os
     import subprocess
     import sys
 
+    if jax.default_backend() == "tpu":
+        if jax.device_count() < 8:
+            raise RuntimeError(
+                f"shard2d rows need 8 devices for meshes (2,4) and (8,1); "
+                f"this TPU host has {jax.device_count()}, and a child "
+                "process cannot share its chips")
+        for row in _shard2d_measurements():
+            report(*row)
+        return
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:

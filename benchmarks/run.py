@@ -145,6 +145,9 @@ def run_check(baseline: dict, rows: list, tolerance: float) -> int:
 
 
 def main() -> None:
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of: " + ",".join(SUITES))
@@ -202,13 +205,12 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=1)
         print(f"wrote {len(_ROWS)} rows to {args.json}", file=sys.stderr)
-    if baseline is not None:
-        regressions = run_check(baseline, _ROWS, args.check_tolerance)
-        if failures:  # a crashed suite can't certify anything
-            print(f"check: {failures} suite error(s) — failing the gate",
-                  file=sys.stderr)
-        if regressions or failures:
-            sys.exit(2)
+    regressions = (run_check(baseline, _ROWS, args.check_tolerance)
+                   if baseline is not None else 0)
+    if failures:  # a crashed suite can't certify anything
+        print(f"{failures} suite error(s) — failing the run", file=sys.stderr)
+    if regressions or failures:
+        sys.exit(2)
 
 
 if __name__ == "__main__":

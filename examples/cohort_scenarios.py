@@ -88,6 +88,7 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.common.compile_cache import enable_compile_cache
     from repro.core import QAFeL, QAFeLConfig
     from repro.data import FederatedPartition, SyntheticCelebA
     from repro.launch.mesh import make_sim_mesh
@@ -95,6 +96,7 @@ def main():
     from repro.sim import (SCENARIOS, CohortAsyncFLSimulator,
                            PopulationAsyncFLSimulator, SimConfig)
 
+    enable_compile_cache()
     if args.list:
         for name, cfg in SCENARIOS.items():
             print(f"{name:20s} {cfg}")

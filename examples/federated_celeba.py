@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import QAFeL, QAFeLConfig
 from repro.data import FederatedPartition, SyntheticCelebA
 from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn
@@ -21,6 +22,7 @@ from repro.sim import AsyncFLSimulator, CohortAsyncFLSimulator, SimConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--uploads", type=int, default=400)
     ap.add_argument("--concurrency", type=int, default=16)

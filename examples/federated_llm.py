@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as config_registry
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.qafel import QAFeLConfig
 from repro.core.staleness import staleness_weight
 from repro.data.synthetic import synthetic_batch_for_config
@@ -28,6 +29,7 @@ def model_drift(x, hidden):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--rounds", type=int, default=8)

@@ -9,6 +9,7 @@ and the quantized hidden-state broadcast keeping every replica bit-identical.
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import QAFeL, QAFeLConfig, decode_message
 
 D = 2048
@@ -27,6 +28,7 @@ def trees_equal(a, b) -> bool:
 
 
 def main():
+    enable_compile_cache()
     qcfg = QAFeLConfig(
         client_lr=0.2, server_lr=1.0, server_momentum=0.3,
         buffer_size=4, local_steps=2,

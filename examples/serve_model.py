@@ -15,11 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as config_registry
+from repro.common.compile_cache import enable_compile_cache
 from repro.data.synthetic import synthetic_batch_for_config
 from repro.models import transformer as T
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-1.3b")
     ap.add_argument("--batch", type=int, default=2)

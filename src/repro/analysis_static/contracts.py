@@ -534,6 +534,13 @@ def run_compiled(ndevs: Sequence[int] = (1,)) -> CompiledResult:
     for ndev in ndevs:
         if ndev <= jax.device_count():
             res = _run_in_process(ndev)
+        elif jax.default_backend() == "tpu":
+            # one process owns the chips: a child could not reach them
+            res = CompiledResult([Finding(
+                "compiled-pass-error", f"hlo://tpu?ndev={ndev}", 0, 0,
+                f"ndev={ndev} exceeds the {jax.device_count()} visible TPU "
+                "chip(s); virtual devices exist only on the CPU backend")],
+                0)
         else:
             res = _run_subprocess(ndev)
         findings.extend(res.findings)

@@ -1,9 +1,7 @@
-"""JAX API-drift shims.
+"""JAX API shims.
 
-``jax.shard_map`` graduated from ``jax.experimental.shard_map`` with renamed
-keywords (``check_rep`` -> ``check_vma``, ``auto`` -> complement of
-``axis_names``). The repo targets both: new API when present, else the
-experimental one with translated kwargs.
+``shard_map`` takes the manual axes as ``axis_names`` (a set); callers pass
+any iterable.
 """
 from __future__ import annotations
 
@@ -11,31 +9,15 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` with graceful fallback to the experimental API.
+    """``jax.shard_map`` with optional keywords left at jax's defaults.
 
     axis_names: axes that are Manual inside ``f`` (others stay auto/GSPMD).
-    check_vma:  replication checking (``check_rep`` pre-graduation).
+    check_vma:  replication checking.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
     kwargs = {}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None and frozenset(axis_names) != frozenset(mesh.axis_names):
-        # Pre-graduation partial-manual (``auto=...``) miscompiles collectives
-        # in older XLA (spmd_partitioner.cc manual-subgroup check fails on
-        # all_to_all/all_gather). Fall back to FULL manual: axes the caller
-        # wanted auto simply don't appear in any spec, so inputs/outputs are
-        # replicated across them and the body's compute is duplicated —
-        # bit-identical results, just without intra-body tensor parallelism.
-        # Replication across the formerly-auto axes can't be proven by the
-        # rep-checker, so it must be off.
-        kwargs["check_rep"] = False
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -377,7 +377,7 @@ def qsgd_encode_flat2d(flat2d: jnp.ndarray, keys, bits: int, *,
 
             def body(_, xs):
                 x_c, i = xs
-                u_c = _kq.threefry_uniform_rows(keys, i * c, c, rows)
+                u_c = _kq.threefry_uniform_rows(keys, i * c, c)
                 return None, _kq._quantize_pack_block(x_c, u_c, bits)
 
             _, (p3, n3) = jax.lax.scan(body, None, (x3, jnp.arange(nch)))

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.qsgd import BLOCK_ROWS, LANES
+from repro.kernels.qsgd import BLOCK_ROWS, LANES, _decode_codes
 
 
 def _weighted_dequant_sum(w, p, n3, *, bits: int, k: int, rows: int):
@@ -34,16 +34,9 @@ def _weighted_dequant_sum(w, p, n3, *, bits: int, k: int, rows: int):
     128) f32. ``w``/``p``/``n3`` may be arrays or pallas refs (indexed per
     k); accumulation is an ascending-k fori_loop on both routes."""
     s = (1 << (bits - 1)) - 1
-    per_byte = 8 // bits
-    code_mask = jnp.uint32((1 << bits) - 1)
-    mag_mask = jnp.uint32(s)
-    shifts = (jnp.arange(per_byte, dtype=jnp.uint32) * bits).reshape(1, 1, per_byte)
 
     def body(i, acc):
-        pi = p[i].astype(jnp.uint32)  # (rows, LANES/per_byte)
-        codes = ((pi[:, :, None] >> shifts) & code_mask).reshape(rows, LANES)
-        mag = (codes & mag_mask).astype(jnp.float32)
-        sign = 1.0 - 2.0 * ((codes >> (bits - 1)) & 1).astype(jnp.float32)
+        sign, mag = _decode_codes(p[i], bits)  # p[i]: (rows, LANES/per_byte)
         scale = w[i, 0] * n3[i] / float(s)  # (rows, 1): weight * norms / s
         return acc + sign * mag * scale
 

@@ -34,8 +34,30 @@ TILE = _qsgd.BLOCK_ROWS * _qsgd.LANES  # elements per grid block
 BUCKET = _qsgd.LANES  # one fp32 norm per 128-element row
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(*operands) -> bool:
+    """Whether a kernel call takes its interpret / XLA route: off a TPU, and
+    on a TPU when a concrete operand is spread over several devices. A
+    Mosaic kernel is a one-device program that GSPMD cannot partition, so
+    outside a ``shard_map`` (the replicas decoding a mesh flush's broadcast)
+    the bit-identical jnp block math runs instead. A tracer counts as one
+    device: inside a jit the enclosing program placed it."""
+    if jax.default_backend() != "tpu":
+        return True
+    return any(isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
+               and len(a.sharding.device_set) > 1 for a in operands)
+
+
+def _kernel_entry(*static_argnames):
+    """Jit a kernel wrapper whose static ``interpret`` is chosen per call
+    from its operands (``_interpret``), before jit hides their placement."""
+    def deco(body):
+        jitted = jax.jit(body, static_argnames=static_argnames + ("interpret",))
+
+        @functools.wraps(body)
+        def entry(*args, **kwargs):
+            return jitted(*args, interpret=_interpret(*args), **kwargs)
+        return entry
+    return deco
 
 
 def padded_len(n: int) -> int:
@@ -63,8 +85,8 @@ def _pad_rows(x2d: jnp.ndarray, tile_rows: int) -> jnp.ndarray:
     return x2d
 
 
-@functools.partial(jax.jit, static_argnames=("bits",))
-def qsgd_quantize(flat: jnp.ndarray, key, bits: int = 4):
+@_kernel_entry("bits")
+def qsgd_quantize(flat: jnp.ndarray, key, bits: int = 4, *, interpret: bool):
     """Quantize a flat f32 vector.
 
     Returns (packed uint8 (rows, 128*bits//8), norms f32 (rows,)) in wire
@@ -82,12 +104,13 @@ def qsgd_quantize(flat: jnp.ndarray, key, bits: int = 4):
     # regardless of noise
     u2d = _pad_rows(jax.random.uniform(key, (rows, BUCKET), dtype=jnp.float32),
                     tile_rows)
-    packed, norms = _qsgd.qsgd_quantize_pack(x2d, u2d, bits, interpret=_interpret())
+    packed, norms = _qsgd.qsgd_quantize_pack(x2d, u2d, bits, interpret=interpret)
     return packed[:rows], norms.reshape(-1)[:rows]
 
 
-@functools.partial(jax.jit, static_argnames=("bits",))
-def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4):
+@_kernel_entry("bits")
+def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4, *,
+                        interpret: bool):
     """Quantize a (B, n) stack of flat f32 messages in ONE kernel dispatch.
 
     ``keys`` is a (B, 2) stack of PRNG keys, one per message; their raw
@@ -109,7 +132,7 @@ def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4):
     x3d = flat_batch.reshape(b, rows, BUCKET)
     seeds = jnp.asarray(keys).reshape(b, -1)[:, :2].astype(jnp.uint32)
     packed, norms = _qsgd.qsgd_quantize_pack_batch(x3d, seeds, bits,
-                                                   interpret=_interpret())
+                                                   interpret=interpret)
     return packed, norms.reshape(b, rows)
 
 
@@ -138,12 +161,13 @@ def qsgd_quantize_chunk(flat_chunk: jnp.ndarray, key, row_start, *,
     * ``threefry=True`` reproduces ``qsgd_quantize``'s b=1 wire convention:
       the dither rows are exact chunks of the full
       ``jax.random.uniform(key, (total_rows, 128))`` field
-      (``qsgd.threefry_uniform_rows`` rebuilds jax's counter pairing per
-      flat index, which is why ``total_rows`` must be the TRUE total).
+      (``qsgd.threefry_uniform_rows`` applies jax's per-index threefry law).
     * ``threefry=False`` is the batched counter-hash convention keyed by
-      the global element index (``row_start`` is the counter offset);
-      ``total_rows`` is ignored by the math but kept in the signature so
-      both paths compile per (shape, message-size) pair.
+      the global element index (``row_start`` is the counter offset).
+
+    Both laws are local to each element index, so ``total_rows`` is ignored
+    by the math; it stays in the signature so every path compiles per
+    (shape, message-size) pair.
 
     Returns ``(packed uint8 (rows_c, 128*bits//8), norms f32 (rows_c,))``.
     """
@@ -152,7 +176,7 @@ def qsgd_quantize_chunk(flat_chunk: jnp.ndarray, key, row_start, *,
     x2d = flat_chunk.astype(jnp.float32).reshape(-1, BUCKET)
     if threefry:
         u2d = _qsgd.threefry_uniform_rows(jnp.asarray(key), row_start,
-                                          x2d.shape[0], total_rows)
+                                          x2d.shape[0])
         packed, norms = _qsgd._quantize_pack_block(x2d, u2d, bits)
         return packed, norms.reshape(-1)
     seeds = jnp.asarray(key).reshape(1, -1)[:, :2].astype(jnp.uint32)
@@ -162,26 +186,28 @@ def qsgd_quantize_chunk(flat_chunk: jnp.ndarray, key, row_start, *,
     return p3[0], n3.reshape(-1)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "n"))
-def qsgd_dequantize(packed: jnp.ndarray, norms: jnp.ndarray, bits: int, n: int) -> jnp.ndarray:
+@_kernel_entry("bits", "n")
+def qsgd_dequantize(packed: jnp.ndarray, norms: jnp.ndarray, bits: int, n: int,
+                    *, interpret: bool) -> jnp.ndarray:
     """Dequantize wire-layout packed codes back to a flat f32 vector of
     length n. (Kernel-tile padding, if the backend needs it, happens inside
     the kernel wrapper.)"""
     x2d = _qsgd.qsgd_unpack_dequantize(jnp.asarray(packed), jnp.asarray(norms),
-                                       bits, interpret=_interpret())
+                                       bits, interpret=interpret)
     return x2d.reshape(-1)[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "n"))
+@_kernel_entry("bits", "n")
 def buffer_aggregate(packed_stack: jnp.ndarray, norms: jnp.ndarray,
-                     weights: jnp.ndarray, bits: int, n: int) -> jnp.ndarray:
+                     weights: jnp.ndarray, bits: int, n: int, *,
+                     interpret: bool) -> jnp.ndarray:
     """Fused weighted dequantized sum over the K buffered messages -> flat (n,).
 
     packed_stack: (K, rows, 128*bits//8) wire-layout codes
     norms:        (K, rows) per-message bucket norms."""
     out2d = _agg.buffer_aggregate(jnp.asarray(packed_stack),
                                   jnp.asarray(norms), weights, bits,
-                                  interpret=_interpret())
+                                  interpret=interpret)
     return out2d.reshape(-1)[:n]
 
 

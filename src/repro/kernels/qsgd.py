@@ -14,9 +14,16 @@ Wire format (per flat message of n elements, padded to LANE*SUBLANE tiles):
 Layout: the flat vector is reshaped to (rows, 128) and tiled with
 BlockSpec((BLOCK_ROWS, 128)) so each grid step streams one VMEM-resident
 block: read x + uniform noise, emit packed codes + carry per-row norms.
-Everything is elementwise on the VPU; arithmetic intensity is O(1) so the
-kernel is HBM-bandwidth-bound by design — the point is to touch each
-element exactly once.
+Arithmetic intensity is O(1) so the kernel is HBM-bandwidth-bound by
+design — the point is to touch each element exactly once.
+
+Packing ``8 // bits`` lanes into one byte is a lane-regrouping, which
+Mosaic cannot express as a reshape; both directions are therefore one small
+matmul against a 0/power-of-two placement matrix (``_pack_codes`` /
+``_unpack_codes``). Every operand is an integer below 256, exact in bf16,
+and the sums accumulate in f32, so the packed bytes are the little-endian
+layout above bit for bit. Unsigned<->float conversions go through int32,
+the one integer type Mosaic converts to and from f32.
 """
 from __future__ import annotations
 
@@ -27,11 +34,75 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128  # bucket size: one norm per 128-element row
-BLOCK_ROWS = 256  # (256, 128) fp32 block = 128 KiB in VMEM; well under budget
+BLOCK_ROWS = 256  # (256, 128) fp32 block = 128 KiB in VMEM
 
 # ---------------------------------------------------------------------------
 # Quantize + pack
 # ---------------------------------------------------------------------------
+
+
+def _pack_codes(code, bits: int):
+    """f32 integer codes (R, 128) in [0, 2**bits) -> packed uint8
+    (R, 128/per_byte): lane ``l`` lands in byte ``l // per_byte`` at bit
+    ``bits * (l % per_byte)``. One matmul against the placement matrix
+    ``P[l, j] = 2**(bits * (l % per_byte)) * [l // per_byte == j]``, built
+    from iotas because Pallas refuses captured constants."""
+    per_byte = 8 // bits
+    if per_byte > 1:
+        log_pb = per_byte.bit_length() - 1
+        shape = (LANES, LANES // per_byte)
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        weight = jnp.left_shift(1, bits * (lane & (per_byte - 1)))
+        place = jnp.where((lane >> log_pb) == col, weight, 0)
+        code = jnp.dot(code.astype(jnp.bfloat16), place.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    return code.astype(jnp.int32).astype(jnp.uint8)
+
+
+def _unpack_codes(p, bits: int):
+    """packed uint8 (R, 128/per_byte) -> int32 codes (R, 128), the inverse
+    of ``_pack_codes``: one matmul against the 0/1 expansion matrix copies
+    byte ``l // per_byte`` into lane ``l``, then a per-lane shift and mask
+    pick the lane's code."""
+    per_byte = 8 // bits
+    byte = p.astype(jnp.int32)
+    if per_byte == 1:
+        return byte
+    log_pb = per_byte.bit_length() - 1
+    shape = (LANES // per_byte, LANES)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    expand = ((lane >> log_pb) == row).astype(jnp.bfloat16)
+    byte = jnp.dot(byte.astype(jnp.bfloat16), expand,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    shift = bits * (jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+                    & (per_byte - 1))
+    return (byte >> shift) & ((1 << bits) - 1)
+
+
+def _decode_codes(p, bits: int):
+    """packed uint8 (R, 128/per_byte) -> (sign, magnitude) f32 (R, 128)."""
+    s = (1 << (bits - 1)) - 1
+    codes = _unpack_codes(p, bits)
+    mag = (codes & s).astype(jnp.float32)
+    sign = 1.0 - 2.0 * ((codes >> (bits - 1)) & 1).astype(jnp.float32)
+    return sign, mag
+
+
+def tree_sum(v):
+    """Sum over the last axis (a power-of-two width), keeping it as size 1,
+    in one fixed order: a halving tree (element ``l`` + element ``l + w``
+    for w = width/2, ..., 1). ``jnp.sum`` leaves the order to the compiler,
+    which picks it per shape and backend (Mosaic, XLA on a block, XLA on a
+    whole message), and a different order moves the sum by an ulp; adds of
+    slices are never reassociated, so every route agrees bit for bit."""
+    w = v.shape[-1]
+    assert w & (w - 1) == 0, w
+    while w > 1:
+        w //= 2
+        v = v[..., :w] + v[..., w:]
+    return v
 
 
 def _quantize_pack_block(x, u, bits: int):
@@ -39,23 +110,17 @@ def _quantize_pack_block(x, u, bits: int):
     (R, 128/per_byte), norms f32 (R, 1)). Used by both the single-message
     and the batched kernel so the two are bit-identical per row."""
     s = (1 << (bits - 1)) - 1
-    per_byte = 8 // bits
     x = x.astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(x * x, axis=1, keepdims=True))  # (R, 1)
+    norm = jnp.sqrt(tree_sum(x * x))  # (R, 1)
     inv = jnp.where(norm > 0.0, s / jnp.maximum(norm, 1e-30), 0.0)
 
     level = jnp.abs(x) * inv
     low = jnp.floor(level)
     xi = low + (u < (level - low)).astype(jnp.float32)  # stochastic rounding
-    xi = jnp.minimum(xi, float(s)).astype(jnp.uint32)
-    sign_bit = (x < 0.0).astype(jnp.uint32) << (bits - 1)
-    code = sign_bit | xi  # n-bit code
-
-    r = code.shape[0]
-    grouped = code.reshape(r, LANES // per_byte, per_byte)
-    shifts = (jnp.arange(per_byte, dtype=jnp.uint32) * bits).reshape(1, 1, per_byte)
-    packed = jnp.sum(grouped << shifts, axis=-1).astype(jnp.uint8)
-    return packed, norm
+    xi = jnp.minimum(xi, float(s))
+    # n-bit code: the sign bit (MSB) above the magnitude, as an exact f32
+    code = xi + jnp.where(x < 0.0, float(1 << (bits - 1)), 0.0)
+    return _pack_codes(code, bits), norm
 
 
 def _quantize_pack_kernel(x_ref, u_ref, out_ref, norm_ref, *, bits: int):
@@ -128,7 +193,8 @@ def _hash_uniform(seed0, seed1, idx):
     x = fmix32(idx * jnp.uint32(0x9E3779B9) + seed0)
     x = fmix32(x ^ seed1)
     # top 24 bits -> [0, 1): exactly representable in f32
-    return (x >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    return ((x >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def _quantize_pack_batch_block(x, seed0, seed1, row_offset, bits: int):
@@ -273,7 +339,7 @@ def sketch_project(c2d, seeds, group: int):
     assert dpad % group == 0, (dpad, group)
     idx = jnp.arange(dpad, dtype=jnp.uint32)
     s = sketch_signs(seeds[0], seeds[1], idx)
-    y = (c2d * s).reshape(b, dpad // group, group).sum(axis=-1)
+    y = tree_sum((c2d * s).reshape(b, dpad // group, group))[..., 0]
     return y * jnp.float32(1.0 / float(group) ** 0.5)
 
 
@@ -295,31 +361,26 @@ def sketch_expand(y2d, seeds, group: int, offset=0):
 # ---------------------------------------------------------------------------
 
 
-def threefry_uniform_rows(key, row_start, rows: int, total_rows: int,
-                          lanes: int = LANES):
+def threefry_uniform_rows(key, row_start, rows: int, lanes: int = LANES):
     """Rows [row_start, row_start+rows) of the EXACT uniform field
-    ``jax.random.uniform(key, (total_rows, lanes), f32)`` — the b=1 wire
+    ``jax.random.uniform(key, (total_rows, lanes), f32)``, for any
+    ``total_rows >= row_start + rows`` — the b=1 wire
     convention's dither — without materializing the whole field.
 
-    jax's threefry stream for an even-size draw of n elements pairs counter
-    i with i+n/2 and emits cipher word 0 for the first half, word 1 for the
-    second; this reproduces that pairing per flat index (``row_start`` may
-    be traced — one compilation covers every chunk of a given shape) and
-    applies the same bits->f32 mapping (top 23 bits into the mantissa of
-    1.x, minus 1). Bit-exactness with the full draw is pinned in
+    Under jax's partitionable threefry (the default), element ``j`` of a
+    32-bit draw is ``w0 ^ w1`` of ``threefry_2x32(key, (hi=0, lo=j))`` for
+    its flat index ``j``: a law local to each index, so any row chunk is
+    that law on its own indices (``row_start`` may be traced — one
+    compilation covers every chunk of a given shape). The bits->f32 mapping
+    is jax's (top 23 bits into the mantissa of 1.x, minus 1). Bit-exactness with the full draw is pinned in
     tests/test_mesh2d.py, chunk-boundary cases included.
     """
     from jax.extend.random import threefry_2x32
-    n = total_rows * lanes  # always even: lanes is a power of two
-    h = n // 2
     j = (jnp.uint32(row_start) * jnp.uint32(lanes)
          + jnp.arange(rows * lanes, dtype=jnp.uint32))
-    lo = jnp.where(j < h, j, j - jnp.uint32(h))
-    hi = lo + jnp.uint32(h)
     out = threefry_2x32(jnp.asarray(key).reshape(-1)[:2].astype(jnp.uint32),
-                        jnp.concatenate([lo, hi]))
-    m = rows * lanes
-    bits32 = jnp.where(j < h, out[:m], out[m:])
+                        jnp.concatenate([jnp.zeros_like(j), j]))
+    bits32 = out[:j.size] ^ out[j.size:]
     u = jax.lax.bitcast_convert_type(
         (bits32 >> 9) | jnp.uint32(0x3F800000), jnp.float32) - 1.0
     return u.reshape(rows, lanes)
@@ -334,15 +395,7 @@ def _unpack_dequantize_block(p, norm2d, bits: int):
     """Shared block math: packed uint8 (R, 128/per_byte) + norms (R, 1) ->
     f32 (R, 128). Used by the kernel and the fused off-TPU route."""
     s = (1 << (bits - 1)) - 1
-    per_byte = 8 // bits
-    mag_mask = jnp.uint32(s)
-    code_mask = jnp.uint32((1 << bits) - 1)
-    p = p.astype(jnp.uint32)
-    r = p.shape[0]
-    shifts = (jnp.arange(per_byte, dtype=jnp.uint32) * bits).reshape(1, 1, per_byte)
-    codes = ((p[:, :, None] >> shifts) & code_mask).reshape(r, LANES)
-    mag = (codes & mag_mask).astype(jnp.float32)
-    sign = 1.0 - 2.0 * ((codes >> (bits - 1)) & 1).astype(jnp.float32)
+    sign, mag = _decode_codes(p, bits)
     scale = norm2d / float(s)  # (R, 1), broadcasts over lanes
     return sign * mag * scale
 

@@ -18,7 +18,13 @@ def quantize_pack(x2d: jnp.ndarray, u2d: jnp.ndarray, bits: int):
     s = (1 << (bits - 1)) - 1
     per_byte = 8 // bits
     x = x2d.astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(x * x, axis=1, keepdims=True))
+    # the kernel's summation order (lane l + lane l+w, w = 64 ... 1): an
+    # order left to the compiler differs by an ulp between routes
+    sq = x * x
+    while sq.shape[1] > 1:
+        half = sq.shape[1] // 2
+        sq = sq[:, :half] + sq[:, half:]
+    norm = jnp.sqrt(sq)
     inv = jnp.where(norm > 0.0, s / jnp.maximum(norm, 1e-30), 0.0)
     level = jnp.abs(x) * inv
     low = jnp.floor(level)

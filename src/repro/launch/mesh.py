@@ -9,21 +9,30 @@ broadcast is what crosses it.
 Defined as functions (never module-level constants) so importing this
 module touches no jax device state — required for the dry-run's
 host-device-count trick to work.
+
+Every axis is Auto: sharding propagates through GSPMD and ``shard_map``
+bodies, which the flat substrate's reshapes rely on (``jax.make_mesh``
+defaults to Explicit axes, under which those reshapes are type errors).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """A 1x1 mesh on whatever single device is present (CPU smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_sim_mesh(n_dev: int | None = None):
@@ -44,7 +53,7 @@ def make_sim_mesh(n_dev: int | None = None):
             f"make_sim_mesh({n_dev}) but only {jax.device_count()} device(s) "
             "are visible; set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={n_dev} before importing jax to fake them on CPU")
-    return jax.make_mesh((n_dev,), ("data",))
+    return _auto_mesh((n_dev,), ("data",))
 
 
 def make_sim_mesh2d(shape: tuple[int, int] | None = None):
@@ -66,4 +75,4 @@ def make_sim_mesh2d(shape: tuple[int, int] | None = None):
             f"only {jax.device_count()} are visible; set "
             "XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n_data * n_model} before importing jax to fake them on CPU")
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

@@ -53,7 +53,8 @@ def test_collective_bytes_parsed():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analyzer import analyze
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         def f(x):
             return x.sum(axis=0)
         xs = jax.ShapeDtypeStruct((64, 1024), jnp.float32)

@@ -32,7 +32,8 @@ def test_expert_parallel_moe_matches_gspmd():
         from repro import configs as C
         from repro.models import moe as moe_lib
         cfg = C.get_reduced("qwen3-moe-235b-a22b")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         moe_lib.set_ep_mesh(mesh)
         params = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model))
@@ -65,7 +66,8 @@ def test_pod_quantized_round_runs():
         qcfg = QAFeLConfig(client_lr=1e-2, server_lr=1.0, buffer_size=4,
                            local_steps=1, client_quantizer="qsgd8",
                            server_quantizer="qsgd8")
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rng = np.random.default_rng(0)
         raw = synthetic_batch_for_config(cfg, rng, 8, 32)
         batch = {k: jnp.asarray(v).reshape((4, 1, 2) + v.shape[1:])

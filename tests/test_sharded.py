@@ -229,6 +229,29 @@ def test_target_accuracy_zero_fires():
     assert res.uploads < 50  # stopped early, not by the upload budget
 
 
+def test_kernel_route_follows_operand_placement():
+    """On a TPU a kernel entry takes the Pallas route only for operands on
+    one device: GSPMD cannot partition a Mosaic kernel, so a mesh-spread
+    operand (a sharded flush's broadcast payload, decoded by the replicas)
+    takes the bit-identical jnp route. Tracers count as one device."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    x = jnp.zeros((jax.device_count() * 128,), jnp.float32)
+    spread = jax.device_put(x, NamedSharding(make_sim_mesh(), P("data")))
+    seen = []
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        assert not kops._interpret(x, np.zeros(3), 4)
+        assert kops._interpret(spread) == (jax.device_count() > 1)
+        jax.jit(lambda v: seen.append(kops._interpret(v)) or v)(spread)
+    finally:
+        jax.default_backend = real
+    assert seen == [False]
+    assert kops._interpret(x)  # off a TPU every entry interprets
+
+
 def test_eight_virtual_devices_end_to_end():
     """Force 8 host-platform devices in a subprocess and re-run the whole
     equivalence battery there: cohort step (b=5 vs ndev=8, rows=3 vs
@@ -246,6 +269,7 @@ def test_eight_virtual_devices_end_to_end():
         T.test_sharded_cohort_step_bit_identical()
         T.test_sharded_flush_bit_identical()
         T.test_sharded_full_sim_bit_identical()
+        T.test_kernel_route_follows_operand_placement()
 
         # sharded(8) save -> single-device load, continue in lockstep
         # (unique temp path: concurrent suite runs must not race on it)
