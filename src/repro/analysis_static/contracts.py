@@ -377,11 +377,11 @@ def _check_encode_chunk(ndev: int, findings: List[Finding]) -> int:
 
     from repro.kernels import ops as kops
     checks = 0
-    rows_c, total_rows = 4, 12
+    rows_c = 4
     flat = jnp.ones((rows_c * kops.BUCKET,), jnp.float32)
     key = jax.random.PRNGKey(0)
     for label, threefry in (("threefry", True), ("hash", False)):
-        kwargs = {"bits": 4, "total_rows": total_rows, "threefry": threefry}
+        kwargs = {"bits": 4, "threefry": threefry}
         t0 = kops.ENCODE_CHUNK_TRACES
         for start in (0, rows_c, 2 * rows_c):
             kops.qsgd_quantize_chunk(flat, key, start, **kwargs)

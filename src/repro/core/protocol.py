@@ -219,9 +219,10 @@ def decode_message(quantizer: Quantizer, msg: Message):
     return quantizer.decode(msg.payload)
 
 
-def decode_message_flat(quantizer: Quantizer, msg: Message):
-    """Decode a packed message to its flat f32 vector (no unflatten)."""
-    return quantizer.decode_flat(msg.payload)
+def decode_message_flat(quantizer: Quantizer, msg: Message, mesh=None):
+    """Decode a packed message to its flat f32 vector (no unflatten);
+    ``mesh`` as in ``Quantizer.decode_flat``."""
+    return quantizer.decode_flat(msg.payload, mesh=mesh)
 
 
 @dataclasses.dataclass

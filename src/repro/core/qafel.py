@@ -642,7 +642,7 @@ class QAFeL:
             r0 = i * c
             p_c, n_c = kops.qsgd_quantize_chunk(
                 delta[r0 * kops.BUCKET:(r0 + c) * kops.BUCKET], k_enc, r0,
-                bits=bits, total_rows=rows)
+                bits=bits)
             rc = min(c, rows - r0)  # true rows of the tail chunk
             chunks.append(packed_qsgd_chunk_payload(
                 np.asarray(p_c[:rc]), np.asarray(n_c[:rc]), bits, n,
@@ -860,14 +860,16 @@ class QAFeL:
             # top_k / rand_k server quantizers have data-dependent wire
             # shapes (argsort / gather): a short flat-vector chain instead
             # of the single fused dispatch — still no pytree anywhere. Under
-            # a mesh the chain runs on the true-n slices and the results are
-            # re-placed as segment vectors.
+            # a mesh the chain runs on one device, over the true-n vectors
+            # (its encode may be a Pallas kernel, which GSPMD cannot
+            # partition), and the results are re-placed as segment vectors.
             delta = batch.reduce()
             beta = self.qcfg.server_momentum if self.qcfg.server_momentum else None
             x_cur, h_cur, m_cur = st.x_flat, st.hidden_flat, st.momentum_flat
             if self.mesh is not None:
-                x_cur, h_cur, m_cur = (x_cur[:batch.n], h_cur[:batch.n],
-                                       m_cur[:batch.n])
+                x_cur, h_cur, m_cur, delta = (
+                    np.asarray(v)[:batch.n]
+                    for v in (x_cur, h_cur, m_cur, delta))
             x_new, m_new = server_apply_flat(
                 x_cur, m_cur, delta, lr=self.qcfg.server_lr, beta=beta)
             diff = x_new - h_cur

@@ -391,6 +391,21 @@ def qsgd_encode_flat2d(flat2d: jnp.ndarray, keys, bits: int, *,
     return qsgd_encode_rows(x3d, seeds, bits, 0, chunk_rows=chunk_rows)
 
 
+def qsgd_decode_flat(packed: jnp.ndarray, norms: jnp.ndarray, bits: int,
+                     n: int) -> jnp.ndarray:
+    """Traceable dequantize of one wire-layout message to its flat (n,) f32
+    vector: the kernels' shared block math in the caller's trace, the
+    decode twin of ``qsgd_encode_flat2d``. For graphs GSPMD partitions (the
+    distributed round), where a Mosaic kernel cannot run; bit-identical to
+    ``kernels.ops.qsgd_dequantize``."""
+    from repro.kernels import qsgd as _kq  # local import: kernels are optional
+
+    rows = packed.shape[0]
+    x2d = _kq._unpack_dequantize_block(
+        packed, jnp.asarray(norms).astype(jnp.float32).reshape(rows, 1), bits)
+    return x2d.reshape(-1)[:n]
+
+
 def qsgd_pack_lastdim(x: jnp.ndarray, key, bits: int, bucket: int = 128):
     """Bucketed qsgd quantize + bit-pack along the LAST dim only.
 
@@ -746,13 +761,18 @@ class Quantizer:
                                       int(y.shape[1]), spec.group,
                                       np.asarray(seeds))
 
-    def decode_flat(self, enc) -> jnp.ndarray:
-        """Dequantize a packed message to its flat f32 vector (no unflatten)."""
+    def decode_flat(self, enc, mesh=None) -> jnp.ndarray:
+        """Dequantize a packed message to its flat f32 vector (no unflatten).
+        ``mesh``: the payload's rows are spread over it (a sharded server's
+        broadcast), so qsgd codes decode row-locally on every device."""
         from repro.kernels import ops as kops
 
         kind = enc["kind"]
         if kind == "identity":
             return enc["payload"]
+        if kind == "qsgd" and mesh is not None:
+            return kops.qsgd_dequantize_on_mesh(enc["packed"], enc["norms"],
+                                                enc["bits"], enc["n"], mesh)
         if kind == "qsgd":
             return kops.qsgd_dequantize(enc["packed"], enc["norms"],
                                         enc["bits"], enc["n"])

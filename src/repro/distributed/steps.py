@@ -43,8 +43,9 @@ from repro.core.hidden_state import hidden_apply
 from repro.core.qafel import (QAFeLConfig, client_update_flat, local_sgd_scan,
                               server_apply, server_apply_flat)
 from repro.core.quantizers import (flatten_tree, lowrank_expand_flat2d,
-                                   make_quantizer, qsgd_encode_flat2d,
-                                   qsgd_pack_lastdim, qsgd_unpack_lastdim)
+                                   make_quantizer, qsgd_decode_flat,
+                                   qsgd_encode_flat2d, qsgd_pack_lastdim,
+                                   qsgd_unpack_lastdim)
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 
@@ -115,16 +116,16 @@ def make_qafel_round(cfg: ModelConfig, qcfg: QAFeLConfig, *,
         """The flat delta the server accumulates: the client's own decoded
         wire bits (real packed codes for qsgd, raw rows for identity, exact
         sparse reconstruction for top_k/rand_k, dequantize-then-expand for
-        lowrank — ``seeds`` is the round's sketch-basis seed pair)."""
-        from repro.kernels import ops as kops  # lazy: kernels stay optional
-
+        lowrank — ``seeds`` is the round's sketch-basis seed pair). The
+        round is one GSPMD program, so decoding is the kernels' block math
+        in-graph (``qsgd_decode_flat``), like the encode."""
         if cq.spec.kind == "qsgd":
-            return kops.qsgd_dequantize(out["packed"][0], out["norms"][0],
-                                        cq.spec.bits, d)
+            return qsgd_decode_flat(out["packed"][0], out["norms"][0],
+                                    cq.spec.bits, d)
         if cq.spec.kind == "lowrank":
             r = cq.spec.rank(d)
-            y = kops.qsgd_dequantize(out["packed"][0], out["norms"][0],
-                                     cq.spec.bits, r)
+            y = qsgd_decode_flat(out["packed"][0], out["norms"][0],
+                                 cq.spec.bits, r)
             return lowrank_expand_flat2d(y[None], seeds, cq.spec.group, d)[0]
         if cq.spec.kind == "identity":
             return out["flat"][0]
@@ -186,7 +187,7 @@ def make_qafel_round(cfg: ModelConfig, qcfg: QAFeLConfig, *,
         if sq.spec.kind == "qsgd":
             bp, bn = qsgd_encode_flat2d(diff[None], k_server, sq.spec.bits,
                                         threefry=True, chunk_rows=chunk_rows)
-            q = kops.qsgd_dequantize(bp[0], bn[0], sq.spec.bits, d)
+            q = qsgd_decode_flat(bp[0], bn[0], sq.spec.bits, d)
         elif sq.spec.kind == "identity":
             q = diff
         else:

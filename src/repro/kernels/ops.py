@@ -34,30 +34,13 @@ TILE = _qsgd.BLOCK_ROWS * _qsgd.LANES  # elements per grid block
 BUCKET = _qsgd.LANES  # one fp32 norm per 128-element row
 
 
-def _interpret(*operands) -> bool:
-    """Whether a kernel call takes its interpret / XLA route: off a TPU, and
-    on a TPU when a concrete operand is spread over several devices. A
-    Mosaic kernel is a one-device program that GSPMD cannot partition, so
-    outside a ``shard_map`` (the replicas decoding a mesh flush's broadcast)
-    the bit-identical jnp block math runs instead. A tracer counts as one
-    device: inside a jit the enclosing program placed it."""
-    if jax.default_backend() != "tpu":
-        return True
-    return any(isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
-               and len(a.sharding.device_set) > 1 for a in operands)
-
-
-def _kernel_entry(*static_argnames):
-    """Jit a kernel wrapper whose static ``interpret`` is chosen per call
-    from its operands (``_interpret``), before jit hides their placement."""
-    def deco(body):
-        jitted = jax.jit(body, static_argnames=static_argnames + ("interpret",))
-
-        @functools.wraps(body)
-        def entry(*args, **kwargs):
-            return jitted(*args, interpret=_interpret(*args), **kwargs)
-        return entry
-    return deco
+def _interpret() -> bool:
+    """Whether kernel calls take their interpret / XLA route: everywhere but
+    a TPU. The Pallas route is a one-device program: callers whose operands
+    are spread over a mesh call the kernels under ``shard_map``
+    (``qsgd_dequantize_on_mesh``, the sharded flush), and GSPMD-partitioned
+    graphs use the shared jnp block math directly."""
+    return jax.default_backend() != "tpu"
 
 
 def padded_len(n: int) -> int:
@@ -85,8 +68,8 @@ def _pad_rows(x2d: jnp.ndarray, tile_rows: int) -> jnp.ndarray:
     return x2d
 
 
-@_kernel_entry("bits")
-def qsgd_quantize(flat: jnp.ndarray, key, bits: int = 4, *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("bits",))
+def qsgd_quantize(flat: jnp.ndarray, key, bits: int = 4):
     """Quantize a flat f32 vector.
 
     Returns (packed uint8 (rows, 128*bits//8), norms f32 (rows,)) in wire
@@ -104,13 +87,12 @@ def qsgd_quantize(flat: jnp.ndarray, key, bits: int = 4, *, interpret: bool):
     # regardless of noise
     u2d = _pad_rows(jax.random.uniform(key, (rows, BUCKET), dtype=jnp.float32),
                     tile_rows)
-    packed, norms = _qsgd.qsgd_quantize_pack(x2d, u2d, bits, interpret=interpret)
+    packed, norms = _qsgd.qsgd_quantize_pack(x2d, u2d, bits, interpret=_interpret())
     return packed[:rows], norms.reshape(-1)[:rows]
 
 
-@_kernel_entry("bits")
-def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4, *,
-                        interpret: bool):
+@functools.partial(jax.jit, static_argnames=("bits",))
+def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4):
     """Quantize a (B, n) stack of flat f32 messages in ONE kernel dispatch.
 
     ``keys`` is a (B, 2) stack of PRNG keys, one per message; their raw
@@ -132,7 +114,7 @@ def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4, *,
     x3d = flat_batch.reshape(b, rows, BUCKET)
     seeds = jnp.asarray(keys).reshape(b, -1)[:, :2].astype(jnp.uint32)
     packed, norms = _qsgd.qsgd_quantize_pack_batch(x3d, seeds, bits,
-                                                   interpret=interpret)
+                                                   interpret=_interpret())
     return packed, norms.reshape(b, rows)
 
 
@@ -144,13 +126,13 @@ def qsgd_quantize_batch(flat_batch: jnp.ndarray, keys, bits: int = 4, *,
 ENCODE_CHUNK_TRACES = 0
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "total_rows", "threefry"))
+@functools.partial(jax.jit, static_argnames=("bits", "threefry"))
 def qsgd_quantize_chunk(flat_chunk: jnp.ndarray, key, row_start, *,
-                        bits: int, total_rows: int, threefry: bool = True):
-    """Encode rows ``[row_start, row_start + rows_c)`` of a flat message of
-    ``total_rows`` wire rows — the streaming quantize-encode of the
-    LLM-scale substrate: full packed codes never materialize on one device;
-    each dispatch sees one fixed-size flat chunk and emits its wire rows.
+                        bits: int, threefry: bool = True):
+    """Encode rows ``[row_start, row_start + rows_c)`` of a flat message —
+    the streaming quantize-encode of the LLM-scale substrate: full packed
+    codes never materialize on one device; each dispatch sees one
+    fixed-size flat chunk and emits its wire rows.
 
     ``flat_chunk`` is ``(rows_c * 128,)`` f32 (the caller zero-pads the tail
     chunk's last row; zero elements encode to zero codes). ``row_start`` is
@@ -159,15 +141,15 @@ def qsgd_quantize_chunk(flat_chunk: jnp.ndarray, key, row_start, *,
     Bit-exactness with the whole-message entries, for any chunking:
 
     * ``threefry=True`` reproduces ``qsgd_quantize``'s b=1 wire convention:
-      the dither rows are exact chunks of the full
-      ``jax.random.uniform(key, (total_rows, 128))`` field
+      the dither rows are exact chunks of the whole message's
+      ``jax.random.uniform(key, (rows, 128))`` field
       (``qsgd.threefry_uniform_rows`` applies jax's per-index threefry law).
     * ``threefry=False`` is the batched counter-hash convention keyed by
       the global element index (``row_start`` is the counter offset).
 
-    Both laws are local to each element index, so ``total_rows`` is ignored
-    by the math; it stays in the signature so every path compiles per
-    (shape, message-size) pair.
+    Both laws are local to each element index, so the message's length
+    never enters: one compilation serves every message with this chunk
+    shape.
 
     Returns ``(packed uint8 (rows_c, 128*bits//8), norms f32 (rows_c,))``.
     """
@@ -186,28 +168,55 @@ def qsgd_quantize_chunk(flat_chunk: jnp.ndarray, key, row_start, *,
     return p3[0], n3.reshape(-1)
 
 
-@_kernel_entry("bits", "n")
-def qsgd_dequantize(packed: jnp.ndarray, norms: jnp.ndarray, bits: int, n: int,
-                    *, interpret: bool) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("bits", "n"))
+def qsgd_dequantize(packed: jnp.ndarray, norms: jnp.ndarray, bits: int, n: int) -> jnp.ndarray:
     """Dequantize wire-layout packed codes back to a flat f32 vector of
     length n. (Kernel-tile padding, if the backend needs it, happens inside
     the kernel wrapper.)"""
     x2d = _qsgd.qsgd_unpack_dequantize(jnp.asarray(packed), jnp.asarray(norms),
-                                       bits, interpret=interpret)
+                                       bits, interpret=_interpret())
     return x2d.reshape(-1)[:n]
 
 
-@_kernel_entry("bits", "n")
+@functools.partial(jax.jit, static_argnames=("bits", "n", "mesh"))
+def qsgd_dequantize_on_mesh(packed, norms, bits: int, n: int, mesh):
+    """``qsgd_dequantize`` for a payload spread over ``mesh`` (a sharded
+    flush's broadcast): decoding is row-local, so each device decodes its
+    own whole wire rows under ``shard_map``, where the Pallas route runs per
+    device; GSPMD cannot partition a Mosaic kernel. Rows pad to a device
+    multiple with zero rows (decoded to zeros, sliced off). Returns the
+    flat (n,) vector, spread over the mesh like the rows."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.common.compat import shard_map as _shard_map
+
+    axes = tuple(mesh.axis_names)
+    packed = jnp.asarray(packed)
+    norms = jnp.asarray(norms).astype(jnp.float32).reshape(-1)
+    rows = packed.shape[0]
+    rpad = (-rows) % mesh.size
+    if rpad:
+        packed = jnp.concatenate(
+            [packed, jnp.zeros((rpad, packed.shape[1]), packed.dtype)])
+        norms = jnp.concatenate([norms, jnp.zeros((rpad,), norms.dtype)])
+    dec = _shard_map(
+        lambda p, nm: _qsgd.qsgd_unpack_dequantize(p, nm, bits,
+                                                   interpret=_interpret()),
+        mesh=mesh, in_specs=(P(axes, None), P(axes)),
+        out_specs=P(axes, None), check_vma=False)
+    return dec(packed, norms).reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "n"))
 def buffer_aggregate(packed_stack: jnp.ndarray, norms: jnp.ndarray,
-                     weights: jnp.ndarray, bits: int, n: int, *,
-                     interpret: bool) -> jnp.ndarray:
+                     weights: jnp.ndarray, bits: int, n: int) -> jnp.ndarray:
     """Fused weighted dequantized sum over the K buffered messages -> flat (n,).
 
     packed_stack: (K, rows, 128*bits//8) wire-layout codes
     norms:        (K, rows) per-message bucket norms."""
     out2d = _agg.buffer_aggregate(jnp.asarray(packed_stack),
                                   jnp.asarray(norms), weights, bits,
-                                  interpret=interpret)
+                                  interpret=_interpret())
     return out2d.reshape(-1)[:n]
 
 

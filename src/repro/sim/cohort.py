@@ -67,10 +67,23 @@ _MEMBER_CHUNK_TARGET = 100_000
 
 
 def auto_member_chunk(b: int, d: int) -> int | None:
-    """The engine's member-chunk policy for one cohort dispatch: ``None``
-    (monolithic vmap) below the threshold, else the largest chunk keeping
-    ``chunk * d`` near the cache-resident target."""
-    if b <= 1 or b * d < _MEMBER_CHUNK_THRESHOLD:
+    """The engine's member-chunk policy for one cohort dispatch.
+
+    On a TPU every member trains in a chunk of its own. XLA's TPU
+    convolution picks its tiling by the batch shape, so a member's f32
+    rounding would depend on how many members share its vmap: on the cohort
+    size, and on the mesh, whose devices each train only their slice.
+    One-member chunks make a member's bits the same on one device and on
+    any mesh, at little cost there (CelebA CNN cohort step on a TPU v5e:
+    6.20 vs 5.98 ms at b=8, 7.18 vs 9.94 ms at b=32).
+
+    Elsewhere: ``None`` (monolithic vmap) below the threshold, else the
+    largest chunk keeping ``chunk * d`` near the cache-resident target."""
+    if b <= 1:
+        return None
+    if jax.default_backend() == "tpu":
+        return 1
+    if b * d < _MEMBER_CHUNK_THRESHOLD:
         return None
     return max(1, min(b, _MEMBER_CHUNK_TARGET // max(d, 1)))
 

@@ -128,7 +128,8 @@ class BaseAsyncSimulator:
         to the server. Evaluates on the server-step cadence; returns True
         when the target accuracy is hit.
         """
-        q = decode_message_flat(self.algo.sq, bmsg)
+        q = decode_message_flat(self.algo.sq, bmsg,
+                                mesh=getattr(self.algo, "mesh", None))
         self.replicas = [rep + q for rep in self.replicas]
         step = self.algo.state.t
         if step - self._last_eval_step >= self.cfg.eval_every_steps:

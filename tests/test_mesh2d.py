@@ -89,7 +89,7 @@ def test_quantize_chunk_threefry_reassembles_whole_message():
             for i in range(nch):
                 p_c, n_c = kops.qsgd_quantize_chunk(
                     full[i * c * kops.BUCKET:(i + 1) * c * kops.BUCKET],
-                    key, i * c, bits=4, total_rows=rows)
+                    key, i * c, bits=4)
                 rc = min(c, rows - i * c)
                 ps.append(np.asarray(p_c[:rc]))
                 ns.append(np.asarray(n_c[:rc]))
@@ -115,7 +115,7 @@ def test_quantize_chunk_counter_hash_matches_batched():
             else padded
         ps = [kops.qsgd_quantize_chunk(
             full[i * c * kops.BUCKET:(i + 1) * c * kops.BUCKET], key, i * c,
-            bits=4, total_rows=rows, threefry=False) for i in range(nch)]
+            bits=4, threefry=False) for i in range(nch)]
         packed = np.concatenate([np.asarray(p) for p, _ in ps])[:rows]
         norms = np.concatenate([np.asarray(nn) for _, nn in ps])[:rows]
         assert_equal(packed, ref_p[0], f"packed c={c}")

@@ -229,27 +229,27 @@ def test_target_accuracy_zero_fires():
     assert res.uploads < 50  # stopped early, not by the upload budget
 
 
-def test_kernel_route_follows_operand_placement():
-    """On a TPU a kernel entry takes the Pallas route only for operands on
-    one device: GSPMD cannot partition a Mosaic kernel, so a mesh-spread
-    operand (a sharded flush's broadcast payload, decoded by the replicas)
-    takes the bit-identical jnp route. Tracers count as one device."""
+def test_mesh_decode_matches_single_device():
+    """A payload spread over the mesh (a sharded flush's broadcast, as the
+    replicas receive it) decodes row-locally under shard_map to the same
+    bits as the one-device decode, for row counts that don't divide the
+    device count; the result stays spread over the mesh."""
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    x = jnp.zeros((jax.device_count() * 128,), jnp.float32)
-    spread = jax.device_put(x, NamedSharding(make_sim_mesh(), P("data")))
-    seen = []
-    real = jax.default_backend
-    jax.default_backend = lambda: "tpu"
-    try:
-        assert not kops._interpret(x, np.zeros(3), 4)
-        assert kops._interpret(spread) == (jax.device_count() > 1)
-        jax.jit(lambda v: seen.append(kops._interpret(v)) or v)(spread)
-    finally:
-        jax.default_backend = real
-    assert seen == [False]
-    assert kops._interpret(x)  # off a TPU every entry interprets
+    mesh = make_sim_mesh()
+    for n, bits in ((307, 4), (1000, 2), (128, 8)):
+        flat = jax.random.normal(jax.random.PRNGKey(n), (n,)) * 3.0
+        packed, norms = kops.qsgd_quantize(flat, jax.random.PRNGKey(1), bits)
+        rows = packed.shape[0]
+        if rows % jax.device_count() == 0:
+            packed = jax.device_put(packed, NamedSharding(mesh, P("data")))
+            norms = jax.device_put(norms, NamedSharding(mesh, P("data")))
+        got = kops.qsgd_dequantize_on_mesh(packed, norms, bits, n, mesh)
+        assert_equal(got, kops.qsgd_dequantize(np.asarray(packed),
+                                               np.asarray(norms), bits, n),
+                     f"n={n} bits={bits}")
+        assert got.sharding.device_set == set(mesh.devices.flat)
 
 
 def test_eight_virtual_devices_end_to_end():
@@ -269,7 +269,7 @@ def test_eight_virtual_devices_end_to_end():
         T.test_sharded_cohort_step_bit_identical()
         T.test_sharded_flush_bit_identical()
         T.test_sharded_full_sim_bit_identical()
-        T.test_kernel_route_follows_operand_placement()
+        T.test_mesh_decode_matches_single_device()
 
         # sharded(8) save -> single-device load, continue in lockstep
         # (unique temp path: concurrent suite runs must not race on it)
