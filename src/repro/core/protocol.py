@@ -27,6 +27,7 @@ from repro.core.quantizers import (Quantizer, TreeLayout,
                                    packed_identity_payload,
                                    packed_lowrank_payload,
                                    packed_qsgd_payload)
+from repro.obs import spans
 
 CLIENT_UPDATE = "client_update"
 HIDDEN_BROADCAST = "hidden_broadcast"
@@ -71,6 +72,14 @@ def frame_packed_message(kind: str, quantizer: Quantizer, enc: dict,
                    meta=dict(meta))
 
 
+def _to_host(*arrays) -> List[np.ndarray]:
+    """Host numpy copies of device arrays, their bytes added to the
+    host-link counter ``spans.d2h_bytes``."""
+    out = [np.asarray(a) for a in arrays]
+    spans.d2h_bytes += sum(a.nbytes for a in out)
+    return out
+
+
 def payloads_from_fused(quantizer: Quantizer, out: dict, layout: TreeLayout,
                         enc_keys=None, *, count: Optional[int] = None,
                         to_numpy: bool = False, basis_seed=None) -> List[dict]:
@@ -82,17 +91,18 @@ def payloads_from_fused(quantizer: Quantizer, out: dict, layout: TreeLayout,
     group's real members must not be encoded (for sparse kinds each row is
     a real argsort/choice dispatch). ``to_numpy=True`` converts the batch
     to host numpy ONCE so the per-member payloads are views (no
-    per-message device ops) — the cohort engine's mode; the sequential b=1
-    caller keeps device arrays. Sparse quantizers (data-dependent wire
-    shapes) encode each member's flat row eagerly through the existing
-    ``encode_flat`` with its per-member key.
+    per-message device ops), counting the copied bytes in
+    ``repro.obs.spans.d2h_bytes`` — the cohort engine's mode; the
+    sequential b=1 caller keeps device arrays. Sparse quantizers
+    (data-dependent wire shapes) encode each member's flat row eagerly
+    through the existing ``encode_flat`` with its per-member key.
     """
     n = layout.total_size
     kind = quantizer.spec.kind
     if kind == "qsgd":
         packed, norms = out["packed"], out["norms"]
         if to_numpy:
-            packed, norms = np.asarray(packed), np.asarray(norms)
+            packed, norms = _to_host(packed, norms)
         count = packed.shape[0] if count is None else count
         return [packed_qsgd_payload(packed[i], norms[i], quantizer.spec.bits,
                                     n, layout)
@@ -102,7 +112,7 @@ def payloads_from_fused(quantizer: Quantizer, out: dict, layout: TreeLayout,
             raise ValueError("lowrank payloads need the round's basis_seed")
         packed, norms = out["packed"], out["norms"]
         if to_numpy:
-            packed, norms = np.asarray(packed), np.asarray(norms)
+            packed, norms = _to_host(packed, norms)
         seed = np.asarray(basis_seed).reshape(-1)[:2].astype(np.uint32)
         rank = quantizer.spec.rank(n)
         count = packed.shape[0] if count is None else count
@@ -114,7 +124,7 @@ def payloads_from_fused(quantizer: Quantizer, out: dict, layout: TreeLayout,
     count = flat.shape[0] if count is None else count
     if kind == "identity":
         if to_numpy:
-            flat = np.asarray(flat)
+            flat, = _to_host(flat)
         return [packed_identity_payload(flat[i], n, layout)
                 for i in range(count)]
     return [quantizer.encode_flat(flat[i], layout, enc_keys[i])

@@ -52,6 +52,7 @@ from repro.core.quantizers import (Quantizer, TreeLayout, flatten_tree,
                                    make_quantizer, packed_identity_payload,
                                    packed_qsgd_payload)
 from repro.core.staleness import StalenessMonitor
+from repro.obs import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -375,6 +376,13 @@ def _hidden_drift_ratio(x_flat, hidden_flat):
 # ---------------------------------------------------------------------------
 # Host orchestration
 # ---------------------------------------------------------------------------
+
+
+def _count_h2d(*arrays) -> None:
+    """Add the host arrays handed to a flush dispatch to the host-link
+    counter (device arrays cross nothing)."""
+    spans.h2d_bytes += sum(a.nbytes for a in arrays
+                           if isinstance(a, np.ndarray))
 
 
 def place_flat_on_mesh(flat, mesh, n: int) -> jnp.ndarray:
@@ -715,7 +723,8 @@ class QAFeL:
                 # different subspace — its message must decode eagerly
                 native = payload.get("group") == self.cq.spec.group
             if native:
-                self.buffer.add_encoded(payload, weight=w)
+                with spans.span("qafel.buffer.add"):
+                    self.buffer.add_encoded(payload, weight=w)
             else:
                 # a bit-width-tier client uploaded through a different
                 # quantizer: its packed payload is self-describing, so decode
@@ -780,6 +789,10 @@ class QAFeL:
         bit-identical. Both the quantize-pack and that decode-apply happen
         inside the single jitted step.
         """
+        with spans.span("qafel.flush", t=self.state.t):
+            return self._flush_body(key, n_receivers)
+
+    def _flush_body(self, key, n_receivers: int) -> Message:
         from repro.kernels import ops as kops  # local import: kernels are optional
 
         st = self.state
@@ -788,7 +801,8 @@ class QAFeL:
         if self.buffer.layout != st.layout:
             raise ValueError("buffered uploads do not match the server's "
                              "parameter layout")
-        batch: FlushBatch = self.buffer.drain(normalize="capacity")
+        with spans.span("qafel.buffer.drain"):
+            batch: FlushBatch = self.buffer.drain(normalize="capacity")
         tap_vec = None
         kind = self.sq.spec.kind
         if kind in ("qsgd", "identity"):
@@ -825,13 +839,15 @@ class QAFeL:
                         [jnp.asarray(extra, jnp.float32),
                          jnp.zeros((rows_pad * kops.BUCKET - batch.n,),
                                    jnp.float32)])
-                out = kops.server_flush_step_sharded(
-                    st.x_flat, st.hidden_flat, st.momentum_flat,
-                    stack, norms, batch.weights, extra, key2d, self._flag,
-                    bits=bits, sbits=sbits, lr=self.qcfg.server_lr,
-                    beta=beta, mesh=self.mesh,
-                    n=batch.n if (self._taps or lowrank_win) else None,
-                    taps=self._taps, chunk_rows=self.chunk_rows, **lkw)
+                with spans.span("qafel.flush.dispatch"):
+                    _count_h2d(stack, norms, extra)
+                    out = kops.server_flush_step_sharded(
+                        st.x_flat, st.hidden_flat, st.momentum_flat,
+                        stack, norms, batch.weights, extra, key2d,
+                        self._flag, bits=bits, sbits=sbits,
+                        lr=self.qcfg.server_lr, beta=beta, mesh=self.mesh,
+                        n=batch.n if (self._taps or lowrank_win) else None,
+                        taps=self._taps, chunk_rows=self.chunk_rows, **lkw)
                 x_new, h_new, m_new, payload = out[:4]
                 if self._taps:
                     tap_vec = out[4]
@@ -840,22 +856,28 @@ class QAFeL:
                 else:
                     payload = (payload[0][:batch.n],)
             else:
-                out = kops.server_flush_step(
-                    st.x_flat, st.hidden_flat, st.momentum_flat,
-                    batch.stack, batch.norms, batch.weights, batch.extra,
-                    key2d, self._flag,
-                    bits=bits, sbits=sbits, n=batch.n,
-                    lr=self.qcfg.server_lr, beta=beta, taps=self._taps,
-                    **lkw)
+                with spans.span("qafel.flush.dispatch"):
+                    _count_h2d(batch.stack, batch.norms, batch.extra)
+                    out = kops.server_flush_step(
+                        st.x_flat, st.hidden_flat, st.momentum_flat,
+                        batch.stack, batch.norms, batch.weights, batch.extra,
+                        key2d, self._flag,
+                        bits=bits, sbits=sbits, n=batch.n,
+                        lr=self.qcfg.server_lr, beta=beta, taps=self._taps,
+                        **lkw)
                 x_new, h_new, m_new, payload = out[:4]
                 if self._taps:
                     tap_vec = out[4]
-            if kind == "qsgd":
-                enc = packed_qsgd_payload(payload[0], payload[1], sbits,
-                                          batch.n, st.layout)
-            else:
-                enc = packed_identity_payload(payload[0], batch.n, st.layout)
-            bmsg = frame_packed_message(HIDDEN_BROADCAST, self.sq, enc, t=st.t)
+            with spans.span("qafel.flush.frame"):
+                if kind == "qsgd":
+                    enc = packed_qsgd_payload(payload[0], payload[1], sbits,
+                                              batch.n, st.layout)
+                else:
+                    enc = packed_identity_payload(payload[0], batch.n,
+                                                  st.layout)
+                bmsg = frame_packed_message(HIDDEN_BROADCAST, self.sq, enc,
+                                            t=st.t)
+                self.meter.record(bmsg, n_receivers=n_receivers)
         else:
             # top_k / rand_k server quantizers have data-dependent wire
             # shapes (argsort / gather): a short flat-vector chain instead
@@ -885,7 +907,7 @@ class QAFeL:
                 x_new = place_flat_on_mesh(x_new, self.mesh, batch.n)
                 h_new = place_flat_on_mesh(h_new, self.mesh, batch.n)
                 m_new = place_flat_on_mesh(m_new, self.mesh, batch.n)
-        self.meter.record(bmsg, n_receivers=n_receivers)
+            self.meter.record(bmsg, n_receivers=n_receivers)
         if self.telemetry is not None:
             extra = {}
             if tap_vec is not None:

@@ -1,6 +1,7 @@
-"""Telemetry substrate: in-dispatch metric taps + structured run tracing.
+"""Telemetry substrate: in-dispatch metric taps, structured run tracing,
+and host spans on the profiler's clock.
 
-Two halves (see DESIGN.md "Telemetry substrate"):
+Three parts (see DESIGN.md "Telemetry substrate"):
 
 * **In-dispatch taps** (``repro.obs.taps``): small flat f32 vectors of
   device-computed scalars — per-upload / per-broadcast relative
@@ -14,6 +15,9 @@ Two halves (see DESIGN.md "Telemetry substrate"):
   and wall-clock timestamps into a bounded in-memory ring, exportable as
   JSONL (schema-checked by ``repro.obs.schema``), plus dispatch/compile
   counters built on ``analysis_static.trace_guard``'s entry registry.
+* **Host spans** (``repro.obs.spans``): ``qafel.*`` profiler annotations
+  inside the engine, the buffer and the flush, on the device trace's
+  clock, and the host-link byte counters ``RunTracer.counters()`` reports.
 
 ``repro.obs.metrics.collect`` is the ONE metrics surface: the pre-existing
 ``TrafficMeter`` / ``StalenessMonitor`` / ``accuracy_trace`` keys are
@@ -23,7 +27,7 @@ when a tracer is attached.
 from repro.obs.events import EVENT_KINDS, CompileWatch, Event, RunTracer
 from repro.obs.metrics import collect
 from repro.obs.records import AccuracyPoint
-from repro.obs.report import report_rows, summary_table, write_jsonl
+from repro.obs.report import summary_table, write_jsonl
 from repro.obs.schema import validate_events, validate_jsonl
 from repro.obs.taps import (COHORT_TAP_NAMES, FLUSH_TAP_NAMES,
                             cohort_tap_rows, flush_tap_vector,
@@ -42,7 +46,6 @@ __all__ = [
     "flush_tap_vector",
     "named_cohort_taps",
     "named_flush_taps",
-    "report_rows",
     "summary_table",
     "validate_events",
     "validate_jsonl",

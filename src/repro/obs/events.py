@@ -162,13 +162,17 @@ class RunTracer:
         return out
 
     def counters(self) -> Dict[str, int]:
-        """Event counts per kind + the absolute dispatch/compile totals."""
+        """Event counts per kind + the absolute dispatch/compile totals and
+        host-link bytes (``repro.obs.spans``)."""
+        from repro.obs import spans
         out = {f"events_{k}": 0 for k in EVENT_KINDS}
         for e in self._events:
             out[f"events_{e.kind}"] += 1
         out["events_evicted"] = self.dropped_events
         for group, total in self._compiles.totals().items():
             out[f"traces_{group}"] = total
+        out["d2h_bytes"] = spans.d2h_bytes
+        out["h2d_bytes"] = spans.h2d_bytes
         return out
 
     def metrics(self) -> Dict[str, Any]:

@@ -1,15 +1,11 @@
-"""Reporters: JSONL artifact, terminal summary table, run.py rows.
+"""Reporters: JSONL artifact and terminal summary table.
 
-Three read-side sinks for one ``RunTracer``:
+Two read-side sinks for one ``RunTracer``:
 
 * ``write_jsonl`` — the archival artifact (one event per line; the CI
   cohort-smoke job uploads this and validates it with ``repro.obs.schema``);
 * ``summary_table`` — a fixed-width terminal table of event counts and
-  tap-series statistics, for ``examples/cohort_scenarios.py`` and friends;
-* ``report_rows`` — ``obs/*`` rows through the ``benchmarks/run.py``
-  ``report()`` callback so tracer aggregates land in the ``--json``
-  artifact next to the perf rows (never gated: the ``--check`` gate only
-  reads ``server/flush_* / sim/cohort_step_* / shard/*`` speedup rows).
+  tap-series statistics, for ``examples/cohort_scenarios.py`` and friends.
 """
 from __future__ import annotations
 
@@ -51,22 +47,3 @@ def summary_table(tracer, *, title: str = "telemetry") -> str:
     if not rows:
         lines.append("(no events recorded)")
     return "\n".join(lines)
-
-
-def report_rows(tracer, report, *, prefix: str = "obs") -> int:
-    """Emit tracer aggregates as ``{prefix}/*`` rows through a
-    ``benchmarks.run.report``-style callback; returns the row count."""
-    emitted = 0
-    counters = tracer.counters()
-    counts = ";".join(f"{k}={v}" for k, v in sorted(counters.items()) if v)
-    report(f"{prefix}/events", 0.0, counts or "empty=1")
-    emitted += 1
-    for key, series in sorted(tracer.metrics().items()):
-        st = _stats(series)
-        if st is None:
-            continue
-        n, lo, mean, hi = st
-        report(f"{prefix}/{key}", 0.0,
-               f"n={n};min={lo:.6g};mean={mean:.6g};max={hi:.6g}")
-        emitted += 1
-    return emitted

@@ -49,6 +49,7 @@ import numpy as np
 from repro.core.protocol import CLIENT_UPDATE, Message, frame_cohort_messages
 from repro.core.qafel import QAFeL
 from repro.core.quantizers import make_quantizer
+from repro.obs import spans
 from repro.sim.events import BaseAsyncSimulator, SimConfig, SimResult
 from repro.sim.scenarios import ScenarioConfig, ScenarioSampler, get_scenario
 
@@ -124,9 +125,10 @@ class CohortAsyncFLSimulator(BaseAsyncSimulator):
         if self.cohort_size == 1:
             return self._next_key()
         if not self._receive_keys:
-            subs = jax.random.split(self.key, 65)
-            self.key = subs[0]
-            self._receive_keys = list(np.asarray(subs[1:]))
+            with spans.span("qafel.engine.receive_key"):
+                subs = jax.random.split(self.key, 65)
+                self.key = subs[0]
+                self._receive_keys = list(np.asarray(subs[1:]))
         return self._receive_keys.pop()
 
     # -- cohort admission -------------------------------------------------
@@ -191,21 +193,25 @@ class CohortAsyncFLSimulator(BaseAsyncSimulator):
                             for i in pad_idx]
                 extra_kw["residual"] = self.algo.client_residuals(cids)
                 extra_kw["basis_seed"] = self.algo.round_basis_seed()
-            out = kops.cohort_train_encode_step(
-                self.algo.loss_fn, self.algo.qcfg, q.spec, st.layout,
-                st.hidden_flat, grp_batches, gt, ge, self.algo._flag, b=b,
-                mesh=self.algo.mesh, taps=self.algo._taps,
-                member_chunk=auto_member_chunk(b, st.layout.total_size),
-                chunk_rows=self.algo.chunk_rows, **extra_kw)
+            with spans.span("qafel.engine.dispatch"):
+                out = kops.cohort_train_encode_step(
+                    self.algo.loss_fn, self.algo.qcfg, q.spec, st.layout,
+                    st.hidden_flat, grp_batches, gt, ge, self.algo._flag,
+                    b=b, mesh=self.algo.mesh, taps=self.algo._taps,
+                    member_chunk=auto_member_chunk(b, st.layout.total_size),
+                    chunk_rows=self.algo.chunk_rows, **extra_kw)
             if cids is not None:
                 self.algo.store_residuals(cids[:members.size],
                                           out["residual"][:members.size])
-            ekeys = np.asarray(ge).reshape(b, -1) if b > 1 else [ge]
-            mlist = frame_cohort_messages(CLIENT_UPDATE, q, out, st.layout,
-                                          enc_keys=ekeys, version=version,
-                                          count=members.size,
-                                          to_numpy=(b > 1),
-                                          basis_seed=extra_kw.get("basis_seed"))
+            with spans.span("qafel.engine.keys"):
+                ekeys = np.asarray(ge).reshape(b, -1) if b > 1 else [ge]
+            # the wait for the step, the codes' copy to the host and the
+            # framing: the device trace tells the wait from the copy
+            with spans.span("qafel.engine.codes_to_host"):
+                mlist = frame_cohort_messages(
+                    CLIENT_UPDATE, q, out, st.layout, enc_keys=ekeys,
+                    version=version, count=members.size, to_numpy=(b > 1),
+                    basis_seed=extra_kw.get("basis_seed"))
             tap_rows = None
             if self.algo._taps:
                 from repro.obs.taps import named_cohort_taps
@@ -233,20 +239,21 @@ class CohortAsyncFLSimulator(BaseAsyncSimulator):
         new_next_arrival = float(arrivals[-1] + inter[-1])
         tiers = self.sampler.tier_indices(b)
 
-        if b == 1:
-            # sequential key order (batches key, then client key) so the
-            # identity-scenario replay is bit-exact
-            batch_keys = [self._next_key()]
-            k_train, k_enc = jax.random.split(self._next_key())
-            train_keys, enc_keys = [k_train], [k_enc]
-        else:
-            # one split covers the whole cohort: 2B+1 subkeys in two device
-            # ops instead of 2B sequential splits
-            subs = jax.random.split(self.key, 2 * b + 1)
-            self.key = subs[0]
-            batch_keys = np.asarray(subs[1:b + 1])
-            te = jax.vmap(jax.random.split)(subs[b + 1:])
-            train_keys, enc_keys = te[:, 0], te[:, 1]
+        with spans.span("qafel.engine.keys"):
+            if b == 1:
+                # sequential key order (batches key, then client key) so
+                # the identity-scenario replay is bit-exact
+                batch_keys = [self._next_key()]
+                k_train, k_enc = jax.random.split(self._next_key())
+                train_keys, enc_keys = [k_train], [k_enc]
+            else:
+                # one split covers the whole cohort: 2B+1 subkeys in two
+                # device ops instead of 2B sequential splits
+                subs = jax.random.split(self.key, 2 * b + 1)
+                self.key = subs[0]
+                batch_keys = np.asarray(subs[1:b + 1])
+                te = jax.vmap(jax.random.split)(subs[b + 1:])
+                train_keys, enc_keys = te[:, 0], te[:, 1]
         # batched-provider protocol: a batches fn marked ``batched = True``
         # is called ONCE with the cohort's client ids + keys and returns an
         # already-stacked tree (leading dim b) — e.g. a view into a
@@ -254,12 +261,14 @@ class CohortAsyncFLSimulator(BaseAsyncSimulator):
         # engine must host-stack (a 39MB copy per cohort at d=98304, the
         # dominant non-compute cost of the encode-bound regime)
         stacked = b > 1 and getattr(self.client_batches_fn, "batched", False)
-        if stacked:
-            batches = self.client_batches_fn(
-                np.arange(next_client, next_client + b), batch_keys)
-        else:
-            batches = [self.client_batches_fn(next_client + i, batch_keys[i])
-                       for i in range(b)]
+        with spans.span("qafel.engine.data"):
+            if stacked:
+                batches = self.client_batches_fn(
+                    np.arange(next_client, next_client + b), batch_keys)
+            else:
+                batches = [self.client_batches_fn(next_client + i,
+                                                  batch_keys[i])
+                           for i in range(b)]
         msgs = self._train_encode_cohort(batches, train_keys, enc_keys, tiers,
                                          stacked=stacked, client0=next_client)
         durations = self.sampler.durations(b)
@@ -292,8 +301,9 @@ class CohortAsyncFLSimulator(BaseAsyncSimulator):
             # which case admission continues until an upload survives)
             next_finish = heap[0][0] if heap else math.inf
             while next_arrival <= next_finish:
-                msgs, arrivals, durations, drops, next_arrival = \
-                    self._admit_cohort(next_arrival, next_client)
+                with spans.span("qafel.engine.admit", client0=next_client):
+                    msgs, arrivals, durations, drops, next_arrival = \
+                        self._admit_cohort(next_arrival, next_client)
                 for i in range(self.cohort_size):
                     if drops[i]:
                         self.dropped += 1
@@ -315,20 +325,21 @@ class CohortAsyncFLSimulator(BaseAsyncSimulator):
                 next_finish = heap[0][0] if heap else math.inf
 
             now, s, cid = heapq.heappop(heap)
-            msg = pending.pop(s)
-            while arrival_heap and arrival_heap[0] <= now:
-                heapq.heappop(arrival_heap)
-                started += 1
-            delivered += 1
-            if self.tracer is not None:
-                self.tracer.set_sim_time(now)
-            bmsg = algo.receive(msg, self._next_receive_key(),
-                                n_receivers=max(1, started - delivered))
-            uploads += 1
-
-            if bmsg is not None:
-                reached = self._apply_broadcast(bmsg, now, uploads,
-                                                accuracy_trace)
+            with spans.span("qafel.engine.deliver", client=cid):
+                msg = pending.pop(s)
+                while arrival_heap and arrival_heap[0] <= now:
+                    heapq.heappop(arrival_heap)
+                    started += 1
+                delivered += 1
+                if self.tracer is not None:
+                    self.tracer.set_sim_time(now)
+                bmsg = algo.receive(msg, self._next_receive_key(),
+                                    n_receivers=max(1, started - delivered))
+                uploads += 1
+                if bmsg is not None:
+                    with spans.span("qafel.engine.replicas"):
+                        reached = self._apply_broadcast(bmsg, now, uploads,
+                                                        accuracy_trace)
 
         return self._finalize(reached=reached, uploads=uploads, now=now,
                               accuracy_trace=accuracy_trace,

@@ -17,7 +17,10 @@ The load-bearing claims pinned here:
 * taps-on is still ONE dispatch per flush / per cohort tier-group
   (trace_guard over the fused-entry counters);
 * every emitted stream passes the JSONL schema validator, and the old
-  metrics keys survive the pipeline unification bit-for-bit.
+  metrics keys survive the pipeline unification bit-for-bit;
+* the program's ``qafel.*`` host spans land in the profiler's trace,
+  nested where the work happens, its host-link counters count the wire
+  bytes, and a run under the profiler is bit-identical to one without.
 """
 import json
 import os
@@ -35,7 +38,7 @@ from repro.core.staleness import StalenessMonitor
 from repro.obs import (COHORT_TAP_NAMES, FLUSH_TAP_NAMES, AccuracyPoint,
                        CompileWatch, Event, RunTracer, summary_table,
                        validate_events, validate_jsonl, write_jsonl)
-from repro.obs.report import report_rows
+from repro.obs import spans
 from repro.obs.schema import _selftest
 from repro.sim import AsyncFLSimulator, CohortAsyncFLSimulator, SimConfig
 
@@ -365,16 +368,8 @@ def test_compile_watch_and_events(traced_run):
     assert all(v == 0 for v in w.poll().values())
 
 
-def test_report_rows_and_summary_table(traced_run):
+def test_summary_table(traced_run):
     _, tracer = traced_run
-    rows = []
-    report_rows(tracer, lambda name, us, derived="": rows.append(
-        (name, us, derived)))
-    names = [r[0] for r in rows]
-    assert "obs/events" in names
-    assert any(n.startswith("obs/flush/") for n in names)
-    # obs rows must never enter the --check speedup gate
-    assert all("speedup" not in n for n in names)
     table = summary_table(tracer)
     assert "events_flush" in table and "flush/bcast_qerr_rel" in table
 
@@ -388,3 +383,116 @@ def test_metrics_surface_keeps_legacy_keys(traced_run):
                 "tau_mean", "tau_hist", "server_steps", "hidden_drift",
                 "replicas_in_sync"):
         assert key in res.metrics, key
+
+
+# -- program spans and host-link counters -------------------------------------
+
+# each qafel.* span and the span it opens inside (None: at top level)
+SPAN_PARENTS = {
+    "qafel.engine.admit": None,
+    "qafel.engine.keys": "qafel.engine.admit",
+    "qafel.engine.data": "qafel.engine.admit",
+    "qafel.engine.dispatch": "qafel.engine.admit",
+    "qafel.engine.codes_to_host": "qafel.engine.admit",
+    "qafel.engine.deliver": None,
+    "qafel.engine.receive_key": "qafel.engine.deliver",
+    "qafel.buffer.add": "qafel.engine.deliver",
+    "qafel.flush": "qafel.engine.deliver",
+    "qafel.buffer.drain": "qafel.flush",
+    "qafel.flush.dispatch": "qafel.flush",
+    "qafel.flush.frame": "qafel.flush",
+    "qafel.engine.replicas": "qafel.engine.deliver",
+}
+
+
+def _cohort_run(profile_dir=None):
+    """A tiny cohort run (qsgd4 both ways, K=2, cohorts of 4), optionally
+    under the profiler: its state, broadcasts, trained members and the
+    host-link bytes it counted."""
+    algo = QAFeL(make_qcfg(buffer_size=2), quad_loss, PARAMS0)
+    trained, broadcasts = [], []
+    receive0 = algo.receive
+
+    def receive(msg, key, n_receivers=1):
+        bmsg = receive0(msg, key, n_receivers)
+        if bmsg is not None:
+            broadcasts.append((np.asarray(bmsg.payload["packed"]),
+                               np.asarray(bmsg.payload["norms"])))
+        return bmsg
+
+    def batches(cid, key):
+        trained.append(cid)
+        return client_batches(cid, key)
+
+    algo.receive = receive
+    scfg = SimConfig(concurrency=8, max_uploads=10, eval_every_steps=1 << 30,
+                     seed=5, track_hidden_replicas=1)
+    sim = CohortAsyncFLSimulator(algo, scfg, batches, eval_fn,
+                                 scenario="identity", cohort_size=4)
+    link0 = (spans.d2h_bytes, spans.h2d_bytes)
+    if profile_dir is None:
+        sim.run()
+    else:
+        opts = jax.profiler.ProfileOptions()
+        opts.host_tracer_level = 1
+        opts.python_tracer_level = 0
+        with jax.profiler.trace(str(profile_dir), profiler_options=opts):
+            sim.run()
+    st = algo.state
+    state = [np.asarray(v) for v in (st.x_flat, st.hidden_flat,
+                                     st.momentum_flat)]
+    return (state, broadcasts, len(trained),
+            (spans.d2h_bytes - link0[0], spans.h2d_bytes - link0[1]))
+
+
+def _program_spans(profile_dir):
+    """The ``qafel.*`` host events of the one XSpace under
+    ``profile_dir``: ``(name, start_ns, end_ns)``."""
+    from jax.profiler import ProfileData
+    path, = profile_dir.glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(e.name, int(e.start_ns), int(e.end_ns))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("qafel.")]
+
+
+def test_program_spans_and_link_counters(tmp_path):
+    from repro.kernels import ops as kops
+    state, bcasts, trained, (d2h, h2d) = _cohort_run(tmp_path)
+    program = _program_spans(tmp_path)
+    by_name = {}
+    for ev in program:
+        by_name.setdefault(ev[0], []).append(ev)
+    assert set(by_name) == set(SPAN_PARENTS), sorted(by_name)
+    for name, parent in SPAN_PARENTS.items():
+        for ev in by_name[name]:
+            around = [p for p in program if p is not ev
+                      and p[1] <= ev[1] and ev[2] <= p[2]]
+            if parent is None:
+                assert not around, (name, [p[0] for p in around])
+            else:
+                assert any(p[0] == parent for p in around), name
+    # the wire bytes of one qsgd4 upload: 64 code bytes and one f32 norm
+    # per 128-value row; every trained member's codes come to the host once,
+    # every flushed upload's codes go back once
+    wire = kops.rows_for(D + 7) * (128 * 4 // 8 + 4)
+    assert trained % 4 == 0
+    assert d2h == trained * wire
+    assert h2d == len(bcasts) * 2 * wire
+    assert len(by_name["qafel.flush"]) == len(bcasts) >= 2
+    # the same seed with the profiler off: the same bits
+    state_off, bcasts_off, trained_off, _ = _cohort_run()
+    assert trained_off == trained
+    for a, b in zip(state, state_off):
+        assert np.array_equal(a.view(np.uint32), b.view(np.uint32))
+    assert len(bcasts_off) == len(bcasts)
+    for (p, n), (p_off, n_off) in zip(bcasts, bcasts_off):
+        assert np.array_equal(p, p_off)
+        assert np.array_equal(n.view(np.uint32), n_off.view(np.uint32))
+
+
+def test_tracer_counters_report_link_bytes():
+    counters = RunTracer().counters()
+    assert counters["d2h_bytes"] == spans.d2h_bytes
+    assert counters["h2d_bytes"] == spans.h2d_bytes
