@@ -22,7 +22,10 @@ Three release surfaces once K samples have arrived:
 * ``drain()`` → ``FlushBatch``: the raw ingredients (stacked codes, norms,
   normalized weights, pre-scaled flat residual) for the fused one-dispatch
   ``server_flush_step`` — the aggregation itself happens *inside* the
-  server's single jitted flush, so no aggregate is materialized here.
+  server's single jitted flush, so no aggregate is materialized here. A
+  window of host-numpy uploads is stacked into one of two reused host
+  staging pairs and, with no decoded residual, launches no device op:
+  the flush dispatch is the first thing that touches the device.
 * ``flush_flat()`` → the aggregated flat f32 Delta-bar (one fused
   dequantize-accumulate kernel pass for qsgd stacks).
 * ``flush()`` → the tree view of ``flush_flat()`` — the legacy surface,
@@ -35,10 +38,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quantizers import Quantizer, TreeLayout, flatten_tree
+from repro.obs import spans
 
 
 @dataclasses.dataclass
@@ -49,6 +54,12 @@ class FlushBatch:
     ``extra`` (identity/sparse/tree-mode residual) is already scaled by
     1/denom, so the consumer's job is exactly
     ``sum_k weights[k] * dequant(stack[k], norms[k]) + extra``.
+
+    ``weights`` is a host numpy array. When every stacked upload was a
+    host numpy array, ``stack`` and ``norms`` are views into one of the
+    buffer's two staging pairs: valid until the drain after next, which
+    rewrites that pair once the outputs last handed to
+    ``UpdateBuffer.fence`` are ready.
     """
 
     n: int
@@ -56,7 +67,7 @@ class FlushBatch:
     bits: Optional[int] = None  # qsgd stack bit-width (None when no stack)
     stack: Any = None  # (K, rows, 128*bits//8) uint8 codes, or None
     norms: Any = None  # (K, rows) f32 bucket norms, or None
-    weights: Any = None  # (K,) f32, normalized, or None
+    weights: Any = None  # (K,) host f32, normalized, or None
     extra: Any = None  # (n,) flat f32 residual, pre-scaled, or None
     # lowrank windows: the stack holds RANK-length subspace wire pairs and
     # every upload carries its own (2,) basis seed (mixed-staleness windows
@@ -105,6 +116,13 @@ class UpdateBuffer:
     _seeds: List[Any] = dataclasses.field(default_factory=list)
     _rank: Optional[int] = None
     _group: Optional[int] = None
+    # host staging of numpy windows (see drain): two (codes, norms) pairs
+    # used in turn, and the outputs last handed to fence()
+    _stage: List[Any] = dataclasses.field(
+        default_factory=lambda: [None, None], repr=False, compare=False)
+    _stage_next: int = dataclasses.field(default=0, repr=False,
+                                         compare=False)
+    _fence: Any = dataclasses.field(default=None, repr=False, compare=False)
 
     def add(self, delta, weight: float = 1.0) -> None:
         """Tree mode: accumulate an already-decoded delta (flattened here)."""
@@ -297,6 +315,46 @@ class UpdateBuffer:
         self.count = 0
         self.flushes += 1
 
+    def _stage_window(self):
+        """Stack the window's host codes and norms into the next staging
+        pair and return views of its first m slots (m packed uploads).
+
+        A pair is (K, *codes) and (K, *norms), allocated at its first use
+        and again only when the wire shape changes. Before a pair is
+        rewritten, the outputs last handed to ``fence`` are waited for: a
+        transfer of numpy arguments may still be running after the
+        dispatch that read the pair returned."""
+        first = [np.asarray(a) for a in self._packed[0]]
+        m = len(self._packed)
+        k = max(self.capacity, m)
+        i = self._stage_next
+        pair = self._stage[i]
+        if pair is not None and all(s.shape == (k,) + a.shape
+                                    and s.dtype == a.dtype
+                                    for s, a in zip(pair, first)):
+            jax.block_until_ready(self._fence)
+            spans.flush_stage_reuses += 1
+        else:
+            pair = tuple(np.empty((k,) + a.shape, a.dtype) for a in first)
+            self._stage[i] = pair
+            spans.flush_stage_allocs += 1
+        self._stage_next = 1 - i
+        codes, norms = pair[0][:m], pair[1][:m]
+        np.stack([p for p, _ in self._packed], out=codes)
+        np.stack([nm for _, nm in self._packed], out=norms)
+        return codes, norms
+
+    def fence(self, outputs) -> None:
+        """Hand over the outputs of the dispatch that read the last drain's
+        batch: no staging pair is rewritten before they are ready.
+
+        The server hands its new state. Each flush reads the state the one
+        before it wrote, so that state is ready only once every earlier
+        flush, and the transfer of every window it read, has finished; and
+        it is donated only by the next flush, after the next drain. A
+        fence holds no device memory beyond the state itself."""
+        self._fence = outputs
+
     def drain(self, *, normalize: str = "capacity") -> FlushBatch:
         """Hand the window's raw ingredients to the fused flush, and reset.
 
@@ -305,6 +363,13 @@ class UpdateBuffer:
         scatter-adds, tree-mode residual) is pre-reduced into one
         pre-scaled flat ``extra`` vector. The op order of the pre-reduction
         matches the eager reference exactly (scaled residual + aggregate).
+
+        ``weights`` is host numpy (the IEEE f32 division of the weights by
+        the denominator). A window whose packed uploads are all host numpy
+        arrays (the cohort engine's) is stacked into a reused staging pair
+        on the host: its ``stack`` and ``norms`` are views that stay valid
+        until the drain after next (see ``fence``). Windows of device
+        arrays are stacked on the device.
         """
         if not self.full:
             raise RuntimeError(f"flush before full: {self.count}/{self.capacity}")
@@ -321,12 +386,11 @@ class UpdateBuffer:
             # K device stacks. Lowrank stacks are RANK-length wire pairs;
             # the per-upload basis seeds ride along as one (K, 2) array.
             if all(isinstance(p, np.ndarray) for p, _ in self._packed):
-                stack = np.stack([p for p, _ in self._packed])
-                norms = np.stack([nm for _, nm in self._packed])
+                stack, norms = self._stage_window()
             else:
                 stack = jnp.stack([p for p, _ in self._packed])
                 norms = jnp.stack([nm for _, nm in self._packed])
-            weights = jnp.asarray(self._weights, jnp.float32) / denom
+            weights = np.asarray(self._weights, np.float32) / np.float32(denom)
             win_kind = kind
             if kind == "lowrank":
                 seeds = np.stack(self._seeds).astype(np.uint32)
@@ -351,7 +415,9 @@ class UpdateBuffer:
 
     def flush_flat(self, *, normalize: str = "capacity"):
         """Return the aggregated flat f32 Delta-bar and reset."""
-        return self.drain(normalize=normalize).reduce()
+        flat = self.drain(normalize=normalize).reduce()
+        self.fence(flat)
+        return flat
 
     def flush(self, *, normalize: str = "capacity"):
         """Return the aggregate Delta-bar as a tree view and reset.

@@ -379,8 +379,10 @@ def _hidden_drift_ratio(x_flat, hidden_flat):
 
 
 def _count_h2d(*arrays) -> None:
-    """Add the host arrays handed to a flush dispatch to the host-link
-    counter (device arrays cross nothing)."""
+    """Add the window's host wire arrays handed to a flush dispatch to the
+    host-link counter (device arrays cross nothing). The (K,) weights and
+    the (2,) key also go as host arrays, 48 bytes at K=10: they are not
+    wire bytes and are not counted."""
     spans.h2d_bytes += sum(a.nbytes for a in arrays
                            if isinstance(a, np.ndarray))
 
@@ -807,14 +809,16 @@ class QAFeL:
         kind = self.sq.spec.kind
         if kind in ("qsgd", "identity"):
             sbits = self.sq.spec.bits if kind == "qsgd" else None
-            key2d = jnp.asarray(key).reshape(1, -1) if kind == "qsgd" else None
+            # the (2,) key goes in as it comes (host numpy from the cohort
+            # engine): the jitted step reshapes it, no eager device op here
+            skey = key if kind == "qsgd" else None
             beta = self.qcfg.server_momentum if self.qcfg.server_momentum else None
             bits = batch.bits if batch.bits is not None else 0
             # lowrank upload window: the stacked wire pairs are RANK-length
             # (never row-padded to the state layout) and the flush needs the
             # static sketch group + the traced (K, 2) per-upload basis seeds
             lowrank_win = batch.kind == "lowrank" and batch.stack is not None
-            lkw = ({"group": batch.group, "lseeds": jnp.asarray(batch.seeds)}
+            lkw = ({"group": batch.group, "lseeds": batch.seeds}
                    if lowrank_win else {})
             if self.mesh is not None:
                 # sharded substrate: pad the window's raw ingredients to the
@@ -843,7 +847,7 @@ class QAFeL:
                     _count_h2d(stack, norms, extra)
                     out = kops.server_flush_step_sharded(
                         st.x_flat, st.hidden_flat, st.momentum_flat,
-                        stack, norms, batch.weights, extra, key2d,
+                        stack, norms, batch.weights, extra, skey,
                         self._flag, bits=bits, sbits=sbits,
                         lr=self.qcfg.server_lr, beta=beta, mesh=self.mesh,
                         n=batch.n if (self._taps or lowrank_win) else None,
@@ -861,7 +865,7 @@ class QAFeL:
                     out = kops.server_flush_step(
                         st.x_flat, st.hidden_flat, st.momentum_flat,
                         batch.stack, batch.norms, batch.weights, batch.extra,
-                        key2d, self._flag,
+                        skey, self._flag,
                         bits=bits, sbits=sbits, n=batch.n,
                         lr=self.qcfg.server_lr, beta=beta, taps=self._taps,
                         **lkw)
@@ -920,6 +924,8 @@ class QAFeL:
             self.telemetry.emit("broadcast", step=st.t + 1,
                                 n_receivers=n_receivers,
                                 wire_kB=bmsg.wire_bytes / 1e3)
+        # the staged window is rewritten only once this state is ready
+        self.buffer.fence(x_new)
         self.state = ServerState(x_flat=x_new, hidden_flat=h_new,
                                  momentum_flat=m_new, layout=st.layout,
                                  t=st.t + 1, mesh=st.mesh)

@@ -665,7 +665,7 @@ def cohort_train_encode_step(loss_fn, qcfg, spec, layout, hidden_flat,
                                     "taps", "group"),
                    donate_argnums=(0, 1, 2))
 def server_flush_step(x_flat, hidden_flat, momentum_flat, stack, norms,
-                      weights, extra, key2d, flag, *,
+                      weights, extra, key, flag, *,
                       bits: int, sbits, n: int, lr: float, beta,
                       taps: bool = False, group=None, lseeds=None):
     """The entire QAFeL buffer flush as ONE jitted, buffer-donated dispatch.
@@ -683,11 +683,15 @@ def server_flush_step(x_flat, hidden_flat, momentum_flat, stack, norms,
 
     ``x_flat`` / ``hidden_flat`` / ``momentum_flat`` are donated: the server
     state is updated in place on device. ``stack`` may be None (no packed
-    qsgd uploads this window), ``beta`` None (no server momentum), ``key2d``
-    None (identity broadcast). ``flag`` is a runtime-True bool array backing
-    the ``hard_boundary`` materialization points that pin bit-exactness
-    with the eager multi-dispatch reference (and with the client replicas,
-    which decode the broadcast bits in their own dispatch).
+    qsgd uploads this window), ``beta`` None (no server momentum), ``key``
+    (the broadcast's (2,) or (1, 2) uint32 key, reshaped in the jit) None
+    (identity broadcast). ``weights``, ``key`` and the stacks may be host
+    numpy arrays: they cross to the device with this dispatch, which is
+    then the flush's only device op. ``flag`` is a runtime-True bool array
+    backing the ``hard_boundary`` materialization points that pin
+    bit-exactness with the eager multi-dispatch reference (and with the
+    client replicas, which decode the broadcast bits in their own
+    dispatch).
 
     Returns ``(x_new, hidden_new, momentum_new, (payload...))`` where the
     payload is ``(packed, norms)`` for a qsgd broadcast or ``(diff,)`` for
@@ -724,7 +728,7 @@ def server_flush_step(x_flat, hidden_flat, momentum_flat, stack, norms,
         h_new = hidden_flat + diff
         q, payload = diff, (diff,)
     else:
-        bp3, bn3 = qsgd_quantize_batch(diff[None], key2d, sbits)
+        bp3, bn3 = qsgd_quantize_batch(diff[None], key, sbits)
         bpacked, bnorms = boundary((bp3[0], bn3[0]))
         q = boundary(qsgd_dequantize(bpacked, bnorms, sbits, n))
         h_new = hidden_flat + q
@@ -742,7 +746,7 @@ def server_flush_step(x_flat, hidden_flat, momentum_flat, stack, norms,
                                     "n", "taps", "chunk_rows", "group"),
                    donate_argnums=(0, 1, 2))
 def server_flush_step_sharded(x_flat, hidden_flat, momentum_flat, stack, norms,
-                              weights, extra, key2d, flag, *,
+                              weights, extra, key, flag, *,
                               bits: int, sbits, lr: float, beta, mesh,
                               n=None, taps: bool = False, chunk_rows=None,
                               group=None, lseeds=None):
@@ -789,7 +793,8 @@ def server_flush_step_sharded(x_flat, hidden_flat, momentum_flat, stack, norms,
 
     Donation keeps the sharded state update in place per device. ``stack``
     may be None (no packed qsgd uploads this window), ``beta`` None (no
-    momentum), ``key2d`` None (identity broadcast). Returns the same
+    momentum), ``key`` (the (2,) or (1, 2) broadcast key, reshaped inside)
+    None (identity broadcast). Returns the same
     ``(x_new, hidden_new, momentum_new, (payload...))`` contract with
     padded-length payload arrays.
 
@@ -847,14 +852,14 @@ def server_flush_step_sharded(x_flat, hidden_flat, momentum_flat, stack, norms,
             bpacked, bnorms.reshape(rows_c, 1), sbits).reshape(-1))
         return bpacked, bnorms, q
 
-    def seg_body(x_l, h_l, m_l, stack_l, norms_l, w, extra_l, key2d_l, flag_l,
+    def seg_body(x_l, h_l, m_l, stack_l, norms_l, w, extra_l, key_l, flag_l,
                  lseeds_l):
         boundary = functools.partial(hard_boundary, flag_l)
         n_l = x_l.shape[0]
         rows_l = n_l // BUCKET
         seg_row0 = flat_segment_index(mesh) * rows_l
         seeds = (None if sbits is None else
-                 jnp.asarray(key2d_l).reshape(1, -1)[:, :2].astype(jnp.uint32))
+                 jnp.asarray(key_l).reshape(1, -1)[:, :2].astype(jnp.uint32))
         if group is not None:
             # lowrank window: expand the replicated subspace stack over this
             # segment's element range only, then hand the delta to the
@@ -965,11 +970,11 @@ def server_flush_step_sharded(x_flat, hidden_flat, momentum_flat, stack, norms,
                   None if norms is None else norms_spec,
                   None if weights is None else rep,
                   None if extra is None else vec,
-                  None if key2d is None else rep, rep,
+                  None if key is None else rep, rep,
                   None if lseeds is None else rep),
         out_specs=out_specs, check_vma=False)
     out = sm(x_flat, hidden_flat, momentum_flat, stack, norms, weights,
-             extra, key2d, flag, lseeds)
+             extra, key, flag, lseeds)
     if not taps:
         return out
     x_new, h_new, m_new, payload, (delta, diff, q) = out

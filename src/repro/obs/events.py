@@ -163,7 +163,7 @@ class RunTracer:
 
     def counters(self) -> Dict[str, int]:
         """Event counts per kind + the absolute dispatch/compile totals and
-        host-link bytes (``repro.obs.spans``)."""
+        host-link bytes and flush staging counts (``repro.obs.spans``)."""
         from repro.obs import spans
         out = {f"events_{k}": 0 for k in EVENT_KINDS}
         for e in self._events:
@@ -173,6 +173,8 @@ class RunTracer:
             out[f"traces_{group}"] = total
         out["d2h_bytes"] = spans.d2h_bytes
         out["h2d_bytes"] = spans.h2d_bytes
+        out["flush_stage_allocs"] = spans.flush_stage_allocs
+        out["flush_stage_reuses"] = spans.flush_stage_reuses
         return out
 
     def metrics(self) -> Dict[str, Any]:

@@ -19,8 +19,13 @@ named ``qafel.*`` and opens where the work happens:
 Two plain counters, in the style of ``kernels.ops.*_TRACES``, count the
 bytes the program itself moves over the host link: ``d2h_bytes``, the
 cohorts' wire codes fetched to the host (``core.protocol``), and
-``h2d_bytes``, the host arrays handed to the flush.
-``RunTracer.counters()`` reports both. Kernels hold no spans.
+``h2d_bytes``, the window's wire arrays (codes, norms, residual) handed to
+the flush; the flush's (K,) weights and (2,) key are not wire bytes and
+are not counted. Two more count the flush's host staging
+(``core.buffer.UpdateBuffer.drain``): ``flush_stage_allocs``, staging
+pairs allocated, and ``flush_stage_reuses``, drains that wrote into a
+pair already there (a steady run allocates two, then only reuses).
+``RunTracer.counters()`` reports all four. Kernels hold no spans.
 """
 from __future__ import annotations
 
@@ -28,6 +33,8 @@ import jax
 
 d2h_bytes = 0
 h2d_bytes = 0
+flush_stage_allocs = 0
+flush_stage_reuses = 0
 
 
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:

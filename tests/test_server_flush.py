@@ -541,3 +541,179 @@ def test_tiered_upload_through_receive_matches_legacy():
     np.testing.assert_array_equal(
         np.asarray(algo.state.hidden_flat),
         np.asarray(flatten_tree(legacy.state.hidden)[0]))
+
+
+# ---------------------------------------------------------------------------
+# UpdateBuffer.drain: host staging of numpy windows
+# ---------------------------------------------------------------------------
+
+
+def _host_upload(q, seed):
+    """A packed upload whose wire arrays are host numpy, as the cohort
+    engine frames them for cohorts of more than one member."""
+    enc = dict(q.encode(f32_tree(seed), jax.random.PRNGKey(seed)))
+    enc["packed"], enc["norms"] = (np.asarray(enc["packed"]),
+                                   np.asarray(enc["norms"]))
+    return enc
+
+
+def _fill_window(buf, q, w, tier=False):
+    """Fill one K-upload window of host uploads (the second upload a
+    decoded qsgd2 tier upload when ``tier``); return what a fresh stack
+    of its packed uploads gives: codes, norms, the device division's
+    weights and the basis seeds."""
+    q2 = make_quantizer("qsgd2")
+    encs, weights = [], []
+    for i in range(buf.capacity):
+        seed = 10 * w + i
+        wt = 1.0 / math.sqrt(1 + (seed % 7))
+        if tier and i == 1:
+            e2 = q2.encode(f32_tree(seed), jax.random.PRNGKey(seed))
+            buf.add_decoded_flat(q2.decode_flat(e2), weight=wt,
+                                 layout=e2["layout"])
+            continue
+        enc = _host_upload(q, seed)
+        buf.add_encoded(enc, weight=wt)
+        encs.append(enc)
+        weights.append(wt)
+    seeds = ([np.asarray(e["seed"], np.uint32).reshape(2) for e in encs]
+             if q.spec.kind == "lowrank" else None)
+    return (np.stack([e["packed"] for e in encs]),
+            np.stack([e["norms"] for e in encs]),
+            np.asarray(jnp.asarray(weights, jnp.float32) / float(buf.capacity)),
+            None if seeds is None else np.stack(seeds))
+
+
+class _Fence:
+    """Outputs handed to ``UpdateBuffer.fence``: counts the waits on it."""
+
+    def __init__(self):
+        self.waits = 0
+
+    def block_until_ready(self):
+        self.waits += 1
+        return self
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+@pytest.mark.parametrize("window", ["qsgd", "tier", "lowrank"])
+def test_staged_drain_equals_fresh_stack(window):
+    """Over consecutive windows of host uploads, the staged drain gives the
+    fresh ``np.stack``'s codes and norms and the device division's weights
+    bit for bit (a tier upload leaves fewer packed uploads than K). A batch
+    shares no memory with the next drain's, is unchanged by it, and the
+    drain after next reuses its staging pair, once the outputs last handed
+    to ``fence`` are ready."""
+    from repro.obs import spans
+
+    q = make_quantizer("lowrank4g32" if window == "lowrank" else "qsgd4")
+    buf = UpdateBuffer(capacity=4, quantizer=q)
+    allocs0, reuses0 = spans.flush_stage_allocs, spans.flush_stage_reuses
+    batches, wants, fences = [], [], []
+    for w in range(4):
+        want = _fill_window(buf, q, w, tier=window == "tier")
+        batch = buf.drain()
+        # the first two drains allocate; each later one waits once on the
+        # fence handed after the drain before it
+        assert [f.waits for f in fences] == [int(j >= 1) for j in range(w)]
+        fences.append(_Fence())
+        buf.fence(fences[-1])
+        assert isinstance(batch.weights, np.ndarray)
+        assert batch.stack.shape[0] == (3 if window == "tier" else 4)
+        got = (batch.stack, batch.norms, batch.weights, batch.seeds)
+        for g, x in zip(got, want):
+            if x is None:
+                assert g is None
+            else:
+                assert g.dtype == x.dtype and g.shape == x.shape
+                np.testing.assert_array_equal(_bits(g), _bits(x))
+        assert (batch.extra is not None) == (window == "tier")
+        if batches:
+            prev, prev_want = batches[-1], wants[-1]
+            assert not np.shares_memory(prev.stack, batch.stack)
+            assert not np.shares_memory(prev.norms, batch.norms)
+            np.testing.assert_array_equal(prev.stack, prev_want[0])
+            np.testing.assert_array_equal(_bits(prev.norms),
+                                          _bits(prev_want[1]))
+        if len(batches) >= 2:
+            assert np.shares_memory(batches[-2].stack, batch.stack)
+        batches.append(batch)
+        wants.append(want)
+    assert spans.flush_stage_allocs - allocs0 == 2
+    assert spans.flush_stage_reuses - reuses0 == 2
+
+
+@pytest.mark.parametrize("name", ["qsgd4", "lowrank4g32"])
+def test_host_window_drain_touches_no_device(name):
+    """A window of host uploads drains with no host-to-device transfer and
+    no device op: the flush dispatch is the first to touch the device."""
+    q = make_quantizer(name)
+    buf = UpdateBuffer(capacity=3, quantizer=q)
+    for w in range(3):
+        _fill_window(buf, q, w)
+        with jax.transfer_guard("disallow"):
+            batch = buf.drain()
+        assert all(isinstance(a, np.ndarray)
+                   for a in (batch.stack, batch.norms, batch.weights))
+
+
+def test_cohort_run_stages_every_flush():
+    """Cohort-engine flushes (host windows) allocate two staging pairs and
+    reuse them for every later flush, and the run's state and broadcasts
+    are bit-identical to the same run stacking every window afresh."""
+    from repro.obs import spans
+    from repro.sim import CohortAsyncFLSimulator, SimConfig
+
+    def run(fresh):
+        qcfg = QAFeLConfig(client_lr=0.05, server_lr=1.0, server_momentum=0.3,
+                           buffer_size=3, local_steps=1,
+                           client_quantizer="qsgd4", server_quantizer="qsgd4")
+        algo = QAFeL(qcfg, quad_loss, {"w": jnp.zeros((300,), jnp.float32)})
+        if fresh:
+            def fresh_stack():
+                return (np.stack([p for p, _ in algo.buffer._packed]),
+                        np.stack([nm for _, nm in algo.buffer._packed]))
+            algo.buffer._stage_window = fresh_stack
+        bcasts = []
+        receive0 = algo.receive
+
+        def receive(msg, key, n_receivers=1):
+            bmsg = receive0(msg, key, n_receivers)
+            if bmsg is not None:
+                bcasts.append((np.asarray(bmsg.payload["packed"]),
+                               np.asarray(bmsg.payload["norms"])))
+            return bmsg
+
+        algo.receive = receive
+
+        def client_batches(cid, key):
+            return {"target": jax.random.normal(key, (1, 300)) + 1.0}
+
+        sim = CohortAsyncFLSimulator(
+            algo, SimConfig(concurrency=8, max_uploads=24,
+                            eval_every_steps=1 << 30, seed=5),
+            client_batches, lambda params: 0.0, scenario="identity",
+            cohort_size=4)
+        counts0 = (spans.flush_stage_allocs, spans.flush_stage_reuses)
+        sim.run()
+        counts = (spans.flush_stage_allocs - counts0[0],
+                  spans.flush_stage_reuses - counts0[1])
+        st = algo.state
+        return ([np.asarray(v) for v in (st.x_flat, st.hidden_flat,
+                                         st.momentum_flat)], bcasts, counts)
+
+    state, bcasts, (allocs, reuses) = run(fresh=False)
+    assert len(bcasts) >= 4
+    assert (allocs, reuses) == (2, len(bcasts) - 2)
+    state_f, bcasts_f, counts_f = run(fresh=True)
+    assert counts_f == (0, 0)
+    for a, b in zip(state, state_f):
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    assert len(bcasts) == len(bcasts_f)
+    for (p, n), (pf, nf) in zip(bcasts, bcasts_f):
+        np.testing.assert_array_equal(p, pf)
+        np.testing.assert_array_equal(_bits(n), _bits(nf))
