@@ -22,6 +22,45 @@ the engine then applies that receive's broadcast to its replicas, and a
 sentinel raised at the next ``receive`` ends ``run()``. A traced run
 traces the window's first ``TRACE_SECONDS``, up to a flush, and keeps
 what the window saw until then for the per-layer metrics.
+
+Adding a cell takes new files only, besides its entries in
+``BENCHMARK.json`` (``bench.selftest``'s ``shrink`` case checks them):
+
+* ``bench/traffic/<traffic>.json``, unless the cell reuses a mix: the keys
+  of ``TRAFFIC_KEYS``;
+* ``bench/configs/<config>.json``, unless it reuses a configuration: the
+  keys of ``CONFIG_KEYS`` (``model``, ``d``, ``client_lr``,
+  ``server_lr``, ``server_momentum``, ``local_steps``), optionally
+  ``reference_block_rows`` (the rows of one block of the reference's
+  flush phase, and of its client phase for a separable model; default
+  ``1 << 30``, one block), and whatever the model module reads;
+* ``bench/models/<model>.py``, unless it reuses a model, defining
+  ``MODEL_NAMES``:
+
+  - ``SEPARABLE``: True where a coordinate's client delta depends on that
+    coordinate of x-hat alone;
+  - ``init_params(key, cfg)``: the weights as a tree, on the device;
+  - ``init_block(key, e0, e1)`` (separable models only): the flat initial
+    weights at indices [e0, e1), equal to those of ``init_params``;
+  - ``make_data(key, cfg, traffic)``: every client's data, on the device;
+  - ``provider(data, cfg, traffic)``: the engine's batched data provider,
+    ``batches(cids, keys)`` with ``batches.batched = True``, whose batch
+    carries ``cid`` (one row per member, repeated per local step);
+  - ``client_batch(data, cfg, cid)``: one client's batches, for the
+    reference;
+  - ``program_loss(params, batch, key)``: the loss the program trains;
+  - ``ref_client_delta(h, batch, k_train, unflatten, cfg, qcfg, dtype)``:
+    the plain reference of the client's local training from x-hat ``h``
+    (a block of whole rows for a separable model, the n values
+    otherwise), returning its f32 delta;
+  - ``client_work(cfg)``: ``flops`` and ``data_bytes`` one upload
+    requires;
+* ``bench/limits/<cell>.json``: the compared numbers' limits, from
+  ``python3 -m bench.control`` on the chip;
+* ``bench/shrink/<cell>.json``: ``{"config": {...}, "traffic": {...}}``,
+  the overrides that shrink the cell for the CPU self-tests;
+* ``bench/metrics/<metric>.py`` for each new per-layer metric: ``read(ctx)``
+  returns the number, or None where the run has nothing to read.
 """
 from __future__ import annotations
 
@@ -50,6 +89,15 @@ TIMING_SEED = 1549775860
 # the device keeps a fixed number of trace events and drops the rest,
 # which a 51-second window of the CNN cell overruns
 TRACE_SECONDS = 10.0
+# what a cell's files must hold (see the module docstring)
+MODEL_NAMES = ("SEPARABLE", "init_params", "make_data", "provider",
+               "client_batch", "program_loss", "ref_client_delta",
+               "client_work")
+CONFIG_KEYS = ("model", "d", "client_lr", "server_lr", "server_momentum",
+               "local_steps")
+TRAFFIC_KEYS = ("engine", "concurrency", "cohort_size", "buffer_size",
+                "client_quantizer", "server_quantizer", "scenario",
+                "replicas")
 
 
 class NoChip(RuntimeError):

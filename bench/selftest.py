@@ -11,6 +11,13 @@ They are not part of the repository's test suite (its ``testpaths`` is
   same inputs: codes and decoded values bit for bit, norms to rounding;
 * ``trace``: the trace reduction on the small recorded TPU trace beside
   this file, against the same numbers taken by brute force;
+* ``reference``: the reference replay on a synthetic record
+  (``bench/test_reference.py``): the running x-hat, the flush phase in row
+  blocks, and live device bytes that do not grow with the versions in use;
+* ``shrink``: every cell has its shrink file, ``bench/shrink/<cell>.json``
+  (``{"config": {...}, "traffic": {...}}``: the same files at small sizes
+  and short windows), and its files hold what ``bench/harness.py``'s
+  docstring asks of a cell;
 * ``sound``: a shrunk run of every one-chip cell, skipping the look for a
   chip, comes out correct;
 * ``control``: the same runs with the bfloat16 reference in the program's
@@ -32,17 +39,6 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 
-# shrunk cells: the same files, small sizes and short windows
-SHRINK = {
-    "cnn.conc1000.qsgd4": {
-        "config": {"image_size": 36, "d": 29922, "clients": 64,
-                   "pool_images": 1024},
-        "traffic": {"concurrency": 40, "cohort_size": 8}},
-    "flat1e8.conc100.qsgd4": {
-        "config": {"d": 131072, "client_lr": 0.05 * 131072,
-                   "reference_block_rows": 256},
-        "traffic": {"concurrency": 30}},
-}
 SEED = 2**31 + 977
 
 
@@ -53,10 +49,17 @@ def _setup():
     return harness
 
 
+def _shrink(name):
+    path = ROOT / "bench" / "shrink" / f"{name}.json"
+    if not path.is_file():
+        raise FileNotFoundError(f"cell {name!r} has no shrink file {path}")
+    return json.loads(path.read_text())
+
+
 def _run(name, patches=(), control=None, seed=SEED):
     harness = _setup()
     from bench import run as brun
-    cell = harness.load_cell(name, SHRINK[name])
+    cell = harness.load_cell(name, _shrink(name))
     dev = harness.device_info(cell.chips, require_tpu=False)
     return brun.measure(cell, seed, 1.0, False, dev, patches=list(patches),
                         control=control)
@@ -165,6 +168,41 @@ def test_trace():
         assert m["unit"] != "%" or v <= 100, (m["name"], v)
 
 
+def test_reference():
+    _setup()
+    from bench import test_reference as tr
+    for name in dir(tr):
+        if name.startswith("test_"):
+            getattr(tr, name)()
+
+
+def test_shrink():
+    harness = _setup()
+    import jax
+    import numpy as np
+    for name in _cells():
+        cell = harness.load_cell(name, _shrink(name))
+        model, c, t = cell.model, cell.config, cell.traffic
+        need = harness.MODEL_NAMES + (("init_block",) if model.SEPARABLE
+                                      else ())
+        missing = [k for k in need if not hasattr(model, k)]
+        missing += [k for k in harness.CONFIG_KEYS if k not in c]
+        missing += [k for k in harness.TRAFFIC_KEYS if k not in t]
+        assert not missing, (name, missing)
+        k_w, k_d = jax.random.split(jax.random.PRNGKey(SEED))
+        if model.SEPARABLE:
+            whole = jax.tree.leaves(model.init_params(k_w, c))
+            assert np.array_equal(
+                np.concatenate([np.asarray(v).reshape(-1) for v in whole]),
+                np.asarray(model.init_block(k_w, 0, c["d"]))), name
+        batches = model.provider(model.make_data(k_d, c, t), c, t)
+        assert getattr(batches, "batched", False), name
+        cids = np.array([3, 5])
+        out = batches(cids, jax.random.split(k_d, 2))
+        got = np.asarray(out["cid"]).reshape(len(cids), -1)
+        assert (got == cids[:, None]).all(), (name, got)
+
+
 def test_sound():
     for name in _cells():
         out = _run(name)
@@ -205,6 +243,7 @@ def test_nochip():
 
 
 CASES = {"work": test_work, "wire": test_wire, "trace": test_trace,
+         "reference": test_reference, "shrink": test_shrink,
          "sound": test_sound, "control": test_control,
          "faults": test_faults, "nochip": test_nochip}
 
